@@ -96,8 +96,8 @@ def main(argv=None) -> int:
                        "deterministic="
                        f"{r['checks']['deterministic']}"),
         "kvi_serve": (bench_kvi_serve,
-                      lambda r: "speedup="
-                      f"{r['checks']['batching_speedup_x']}x,"
+                      lambda r: "batched_fewer_pallas_calls="
+                      f"{r['checks']['batched_fewer_pallas_calls']},"
                       "steady_hit_rate_1="
                       f"{r['checks']['steady_hit_rate_1']},"
                       "deterministic="

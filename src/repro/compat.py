@@ -1,20 +1,10 @@
-"""Small jax version-compat shims (single home, imported lazily).
-
-The repo targets current jax, but the pinned environment may lag: these
-helpers paper over API moves without scattering try/except through the
-codebase.
-"""
+"""Small jax API shims (single home, imported lazily)."""
 from __future__ import annotations
 
 import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` (new) or ``jax.experimental.shard_map.shard_map``
-    (<= 0.4.x, where ``check_vma`` was called ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """``jax.shard_map`` with replication checking off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

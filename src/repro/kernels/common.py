@@ -1,8 +1,8 @@
 """Shared kernel utilities.
 
 All kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling) and are
-validated on CPU with interpret=True against the pure-jnp oracles in
-ref.py. The SPM discipline from the paper maps 1:1: BlockSpecs stage
+validated on CPU with the Pallas interpreter against the pure-jnp oracles
+in ref.py. The SPM discipline from the paper maps 1:1: BlockSpecs stage
 HBM->VMEM lines (kmemld), kernel bodies are fused KVI programs operating on
 VMEM-resident tiles (MFU), outputs stream back (kmemstr).
 """
@@ -10,7 +10,20 @@ from __future__ import annotations
 
 import jax
 
-INTERPRET = jax.default_backend() == "cpu"
+
+def interpret_mode() -> bool:
+    """How every Pallas kernel runs, decided at call time from JAX's
+    default platform: compiled with Mosaic on a TPU, interpreted on the
+    CPU (where the tests run). Any other platform raises, so nothing
+    silently measures the interpreter in place of the chip."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run compiled on a TPU or "
+                       f"interpreted on the CPU; JAX's default platform "
+                       f"is {platform!r}")
 
 
 def round_up(x: int, m: int) -> int:

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import INTERPRET, pick_block
+from repro.kernels.common import interpret_mode, pick_block
 
 NEG_INF = -1e30
 _LANES = 128
@@ -76,8 +76,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0, bq: int = 512,
-                    bk: int = 512, q_offset: int = 0,
-                    interpret: bool = None) -> jax.Array:
+                    bk: int = 512, q_offset: int = 0) -> jax.Array:
     """q: [B, H, Sq, hd]; k, v: [B, KV, Skv, hd]; H = KV * G. -> [B,H,Sq,hd]
     """
     B, H, Sq, hd = q.shape
@@ -109,6 +108,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, _LANES), jnp.float32),     # l
             pltpu.VMEM((bq, hd), jnp.float32),         # acc
         ],
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=interpret_mode(),
     )(qr, kr.reshape(B * KV, Skv, hd), v.reshape(B * KV, Skv, hd))
     return out.reshape(B, H, Sq, hd)

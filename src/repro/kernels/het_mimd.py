@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import INTERPRET
+from repro.kernels.common import interpret_mode
 from repro.kernels.spm_fft import _bitrev
 
 
@@ -69,8 +69,7 @@ def _composite_kernel(img_ref, filt_ref, fre_ref, fim_ref, a_ref, b_ref,
     jax.lax.switch(hart, [conv_branch, fft_branch, mm_branch])
 
 
-def het_mimd_composite(img, filt, fft_re, fft_im, A, B, *,
-                       interpret: bool = None):
+def het_mimd_composite(img, filt, fft_re, fft_im, A, B):
     """Run conv2d(img, filt) + FFT(fft_re/im) + A@B in ONE kernel launch.
     img: [H+F-1, W+F-1] (pre-padded), filt: [F,F], fft_*: [nb, n],
     A: [m, k], B: [k, p]. Returns (conv [H,W], fft_re, fft_im, A@B)."""
@@ -94,6 +93,6 @@ def het_mimd_composite(img, filt, fft_re, fft_im, A, B, *,
             jax.ShapeDtypeStruct((nb, n), jnp.float32),
             jax.ShapeDtypeStruct((m, p), jnp.float32),
         ],
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=interpret_mode(),
     )(img, filt, fft_re, fft_im, A, B, jnp.asarray(_bitrev(n)))
     return outs

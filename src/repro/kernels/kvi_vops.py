@@ -27,7 +27,7 @@ __all__ = ["VOp", "apply_vop", "run_vops"]
 
 def run_vops(program: Sequence[VOp], inputs: Sequence[jax.Array],
              out_slot: Optional[int] = None, n_slots: Optional[int] = None,
-             block: int = 1024, interpret: bool = None) -> jax.Array:
+             block: int = 1024) -> jax.Array:
     """Execute a KVI element-wise program over equal-shaped input vectors.
 
     .. deprecated:: use ``repro.kvi`` (typed IR + pallas backend); this
@@ -49,6 +49,5 @@ def run_vops(program: Sequence[VOp], inputs: Sequence[jax.Array],
         out_slot = program[-1][1]
     x0 = inputs[0]
     out, = fused_elementwise_call(program, list(enumerate(inputs)),
-                                  [out_slot], n_slots=n_slots, block=block,
-                                  interpret=interpret)
+                                  [out_slot], n_slots=n_slots, block=block)
     return out.reshape(x0.shape)

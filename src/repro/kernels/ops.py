@@ -91,17 +91,16 @@ kvred = _kdotp.kvred
 # ---- compute kernels --------------------------------------------------------
 
 matmul_op = jax.jit(spm_matmul, static_argnames=("bm", "bn", "bk",
-                                                 "out_dtype", "interpret"))
-conv2d_op = jax.jit(spm_conv2d, static_argnames=("shift", "block_rows",
-                                                 "interpret"))
-fft_op = jax.jit(spm_fft, static_argnames=("batch_block", "interpret"))
+                                                 "out_dtype"))
+conv2d_op = jax.jit(spm_conv2d, static_argnames=("shift", "block_rows"))
+fft_op = jax.jit(spm_fft, static_argnames=("batch_block",))
 attention_op = jax.jit(flash_attention,
                        static_argnames=("causal", "window", "bq", "bk",
-                                        "q_offset", "interpret"))
+                                        "q_offset"))
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan_op(x, dt, A, B, C, *, chunk: int = 256, interpret=None):
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def ssd_scan_op(x, dt, A, B, C, *, chunk: int = 256):
     """Model-facing wrapper: x [Bz,S,H,P], dt [Bz,S,H], A [H],
     B/C [Bz,S,G,N] (GQA-style groups) — broadcasts groups to heads,
     precomputes da = dt*A, calls the kernel."""
@@ -112,5 +111,5 @@ def ssd_scan_op(x, dt, A, B, C, *, chunk: int = 256, interpret=None):
     Bh = jnp.repeat(B, rep, axis=2)
     Ch = jnp.repeat(C, rep, axis=2)
     da = dt * A[None, None, :]
-    y, state = ssd_scan(x, da, dt, Bh, Ch, chunk=chunk, interpret=interpret)
+    y, state = ssd_scan(x, da, dt, Bh, Ch, chunk=chunk)
     return y, state

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import INTERPRET
+from repro.kernels.common import interpret_mode
 
 
 def _conv_kernel(img_ref, filt_ref, o_ref, *, F: int, bt: int, W: int,
@@ -40,7 +40,7 @@ def _conv_kernel(img_ref, filt_ref, o_ref, *, F: int, bt: int, W: int,
 
 
 def spm_conv2d(img: jax.Array, filt: jax.Array, *, shift: int = 0,
-               block_rows: int = 8, interpret: bool = None) -> jax.Array:
+               block_rows: int = 8) -> jax.Array:
     """img: [H, W] (unpadded); filt: [F, F]. Zero padding, same-size output,
     optional fixed-point post-scale (int32 inputs)."""
     H, W = img.shape
@@ -59,5 +59,5 @@ def spm_conv2d(img: jax.Array, filt: jax.Array, *, shift: int = 0,
         ],
         out_specs=pl.BlockSpec((bt, W), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((H, W), img.dtype),
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=interpret_mode(),
     )(padded, filt)

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import INTERPRET
+from repro.kernels.common import interpret_mode
 
 
 def _bitrev(n: int) -> np.ndarray:
@@ -52,8 +52,7 @@ def _fft_kernel(re_ref, im_ref, perm_ref, ore_ref, oim_ref, *, n: int):
     oim_ref[...] = jnp.take(im, perm, axis=1).astype(oim_ref.dtype)
 
 
-def spm_fft(re: jax.Array, im: jax.Array, *, batch_block: int = 8,
-            interpret: bool = None):
+def spm_fft(re: jax.Array, im: jax.Array, *, batch_block: int = 8):
     """re, im: [B, n] (n a power of two). Returns (re, im) of the DFT."""
     B, n = re.shape
     assert n & (n - 1) == 0, "n must be a power of two"
@@ -70,7 +69,7 @@ def spm_fft(re: jax.Array, im: jax.Array, *, batch_block: int = 8,
                    pl.BlockSpec((bb, n), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((B, n), jnp.float32),
                    jax.ShapeDtypeStruct((B, n), jnp.float32)],
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=interpret_mode(),
     )
     return fn(re.astype(jnp.float32), im.astype(jnp.float32),
               jnp.asarray(_bitrev(n)))

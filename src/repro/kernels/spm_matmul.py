@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import INTERPRET, pick_block
+from repro.kernels.common import interpret_mode, pick_block
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int, out_dtype):
@@ -36,7 +36,7 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int, out_dtype):
 
 
 def spm_matmul(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
-               bk: int = 128, out_dtype=None, interpret: bool = None):
+               bk: int = 128, out_dtype=None):
     """a: [M, K] @ b: [K, N] -> [M, N]. int8 -> int32 accumulate; floats ->
     f32 accumulate in VMEM scratch."""
     M, K = a.shape
@@ -59,5 +59,5 @@ def spm_matmul(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=interpret_mode(),
     )(a, b)

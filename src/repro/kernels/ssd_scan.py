@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import INTERPRET
+from repro.kernels.common import interpret_mode
 
 
 def _ssd_kernel(x_ref, da_ref, dt_ref, b_ref, c_ref, y_ref, state_ref,
@@ -68,7 +68,7 @@ def _ssd_kernel(x_ref, da_ref, dt_ref, b_ref, c_ref, y_ref, state_ref,
 
 
 def ssd_scan(x: jax.Array, da: jax.Array, dt: jax.Array, B: jax.Array,
-             C: jax.Array, *, chunk: int = 256, interpret: bool = None):
+             C: jax.Array, *, chunk: int = 256):
     """x: [Bz, S, H, P]; da, dt: [Bz, S, H]; B, C: [Bz, S, H, N] (already
     head-broadcast). Returns (y [Bz,S,H,P], state [Bz,H,N,P])."""
     Bz, S, H, P = x.shape
@@ -97,6 +97,6 @@ def ssd_scan(x: jax.Array, da: jax.Array, dt: jax.Array, B: jax.Array,
             jax.ShapeDtypeStruct((Bz, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=interpret_mode(),
     )(x, da, dt, B, C)
     return y, state

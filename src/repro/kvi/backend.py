@@ -18,12 +18,10 @@ Backends self-register under a short name::
     get_backend("oracle").run(program)              # one program
     get_backend("oracle").run_workload(workload)    # a composite batch
 
-``available_backends()`` lists what is importable in this environment (the
-Pallas backend needs jax; the registry degrades gracefully without it).
+``available_backends()`` lists the registered backends.
 """
 from __future__ import annotations
 
-import contextlib
 from dataclasses import dataclass, field
 from typing import (TYPE_CHECKING, Callable, Dict, Optional, Protocol,
                     runtime_checkable)
@@ -176,11 +174,10 @@ _BOOTED = False
 
 def _ensure_builtin_backends():
     """Import the built-in backend modules so their ``@register_backend``
-    decorators run. The Pallas backend is optional (requires jax)."""
+    decorators run."""
     global _BOOTED
     if _BOOTED:
         return
     _BOOTED = True
-    from repro.kvi import cyclesim, oracle  # noqa: F401  (side-effect import)
-    with contextlib.suppress(ImportError):     # pragma: no cover - no jax
-        from repro.kvi import pallas_backend  # noqa: F401
+    # side-effect imports
+    from repro.kvi import cyclesim, oracle, pallas_backend  # noqa: F401

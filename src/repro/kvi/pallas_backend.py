@@ -14,10 +14,10 @@ re-planning (through the same planner) only when the program carries no
 plan (``passes=()``) or one planned under different slot-file bounds.
 
 Workload batching: a homogeneous :class:`~repro.kvi.workload.KviWorkload`
-(N data instances of one program structure) executes with a **batch grid
-dimension** — every fused segment is ONE ``pallas_call`` over an
-``(N, grid)`` grid and every reduction is one vmapped kernel launch, so N
-instances cost one compile and one dispatch per segment instead of N.
+(N data instances of one program structure) executes as one **batch** —
+every fused segment is ONE ``pallas_call`` over ``(N, n)`` tiles and every
+reduction is one batched kernel launch, so N instances cost one compile
+and one dispatch per segment instead of N.
 Heterogeneous workloads are grouped by program structure and each group is
 batched the same way.
 
@@ -38,7 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import INTERPRET, pick_block
+from repro.kernels.common import interpret_mode, pick_block
+from repro.kernels.kdotp import reduce_rows
 from repro.kvi.backend import (BackendBase, BackendResult, register_backend)
 from repro.kvi.ir import (ELEMWISE_OPS, KviInstr, KviOp, KviProgram,
                           ScalarBlock, np_dtype)
@@ -58,10 +59,10 @@ _UNSIGNED = {jnp.int8.dtype: jnp.uint8, jnp.int16.dtype: jnp.uint16,
 @dataclass
 class KernelCache:
     """Compiled-call cache: slot-program structure -> a ``jax.jit``-wrapped
-    callable closing over its ``pl.pallas_call`` (or vmapped reduction
+    callable closing over its ``pl.pallas_call`` (or batched reduction
     kernel). Keys carry everything baked into the trace — the op/slot
-    program, batch shape, block split, dtype and interpret flag — so a hit
-    is exactly a compiled executable reuse.
+    program, batch shape, block split and dtype — so a hit is exactly a
+    compiled executable reuse.
 
     An eager interpret-mode ``pallas_call`` re-traces on every invocation
     (~100 ms for even a tiny fused segment); a warm jitted call costs tens
@@ -100,10 +101,19 @@ class KernelCache:
         return {"hits": self.hits, "misses": self.misses,
                 "entries": len(self._fns)}
 
+    def items(self):
+        """The cached ``(key, jitted callable)`` pairs."""
+        return self._fns.items()
 
-def apply_vop(op: str, a, b, imm: int):
+
+def apply_vop(op: str, a, b, imm: int, bits: Optional[int] = None):
     """Element-wise KVI semantics shared by the fused kernel body and the
-    jnp oracle (wrap-around integer arithmetic like the Klessydra MFU)."""
+    jnp oracle (wrap-around integer arithmetic like the Klessydra MFU).
+
+    ``bits`` is the element width when narrow values are carried
+    sign-extended in a wider register (the fused kernel computes sub-word
+    programs in int32 and truncates on store, which keeps the MFU's
+    two's-complement wrap-around); it defaults to the dtype's width."""
     if op == "kaddv":
         return a + b
     if op == "ksubv":
@@ -115,11 +125,22 @@ def apply_vop(op: str, a, b, imm: int):
     if op == "ksvmulsc":
         return a * jnp.asarray(imm, a.dtype)
     if op == "ksrlv":
-        u = _UNSIGNED.get(jnp.dtype(a.dtype), jnp.uint32)
-        ua = a.astype(u)
-        return (ua >> jnp.asarray(imm, u)).astype(a.dtype)
+        width = 8 * jnp.dtype(a.dtype).itemsize
+        bits = bits or width
+        if not 0 <= imm < bits:
+            return jnp.zeros_like(a)
+        if bits == width:
+            u = _UNSIGNED[jnp.dtype(a.dtype)]
+            return (a.astype(u) >> jnp.asarray(imm, u)).astype(a.dtype)
+        # zero-extend from the narrow width before the logical shift
+        a = a & jnp.asarray((1 << bits) - 1, a.dtype)
+        return jax.lax.shift_right_logical(a, jnp.asarray(imm, a.dtype))
     if op == "ksrav":
-        return a >> jnp.asarray(imm, a.dtype)
+        # a shift past the width fills with the sign, as a shift by
+        # width - 1 does
+        width = 8 * jnp.dtype(a.dtype).itemsize
+        return a >> jnp.asarray(imm if 0 <= imm < width else width - 1,
+                                a.dtype)
     if op == "krelu":
         return jnp.maximum(a, jnp.asarray(0, a.dtype))
     if op == "kvslt":
@@ -132,58 +153,53 @@ def apply_vop(op: str, a, b, imm: int):
 
 
 def _fused_kernel(*refs, program: Tuple[SlotOp, ...], in_slots, out_slots,
-                  n_slots: int):
+                  n_slots: int, bits: int, wide):
     in_refs = refs[:len(in_slots)]
     out_refs = refs[len(in_slots):]
+    # sub-word values ride sign-extended in int32; every result wraps back
+    # to the element width (shift up, arithmetic shift down), so ops that
+    # are not modular (krelu, compares, shifts) see what the MFU sees
+    rewrap = 32 - bits if wide == jnp.int32 and bits < 32 else 0
     slots: List = [None] * n_slots
     for r, s in zip(in_refs, in_slots):
-        slots[s] = r[...]
+        slots[s] = r[...].astype(wide)
     for op, dst, s1, s2, imm in program:
         a = slots[s1]
         b = slots[s2] if s2 is not None else None
-        slots[dst] = apply_vop(op, a, b, imm)
+        v = apply_vop(op, a, b, imm, bits=bits)
+        slots[dst] = (v << rewrap) >> rewrap if rewrap else v
     for r, s in zip(out_refs, out_slots):
-        r[...] = slots[s]
+        r[...] = slots[s].astype(r.dtype)
 
 
 def _make_fused_caller(program: Tuple[SlotOp, ...], in_slots: tuple,
-                       out_slots: tuple, n_slots: int, N: Optional[int],
-                       n: int, bl: int, dt, interp: bool) -> Callable:
+                       out_slots: tuple, n_slots: int, N: int, n: int,
+                       bl: int, dt) -> Callable:
     """A callable running the fused slot program as one ``pl.pallas_call``
-    over flat ``(n,)`` vectors (``N is None``) or an ``(N, n)`` batch.
-    Everything shape- or structure-dependent is closed over, so the
-    callable is jit-cacheable by identity (:class:`KernelCache`)."""
-    grid = n // bl
-    kernel = functools.partial(_fused_kernel, program=program,
-                               in_slots=in_slots, out_slots=out_slots,
-                               n_slots=n_slots)
+    over an ``(N, n)`` batch: ``(N, bl)`` tiles, so a block's last two
+    dimensions are the whole batch and a lane-aligned (or whole) vector
+    window. Integer programs compute in int32 whatever their element
+    width (the TPU's vector unit has no 8-bit arithmetic) and narrow on
+    store. Everything shape- or structure-dependent is closed over, so
+    the callable is jit-cacheable by identity (:class:`KernelCache`)."""
+    integer = jnp.issubdtype(dt, jnp.integer)
+    kernel = functools.partial(
+        _fused_kernel, program=program, in_slots=in_slots,
+        out_slots=out_slots, n_slots=n_slots,
+        bits=8 * jnp.dtype(dt).itemsize,
+        wide=jnp.int32 if integer else dt)
+    spec = pl.BlockSpec((N, bl), lambda i: (0, i))
 
     def call(*arrs):
-        if N is not None:
-            outs = pl.pallas_call(
-                kernel,
-                grid=(N, grid),
-                in_specs=[pl.BlockSpec((1, 1, bl), lambda b, i: (b, i, 0))
-                          for _ in arrs],
-                out_specs=[pl.BlockSpec((1, 1, bl), lambda b, i: (b, i, 0))
-                           for _ in out_slots],
-                out_shape=[jax.ShapeDtypeStruct((N, grid, bl), dt)
-                           for _ in out_slots],
-                interpret=interp,
-            )(*[x.reshape(N, grid, bl) for x in arrs])
-            return [o.reshape(N, n) for o in outs]
-        outs = pl.pallas_call(
+        return pl.pallas_call(
             kernel,
-            grid=(grid,),
-            in_specs=[pl.BlockSpec((1, bl), lambda i: (i, 0))
-                      for _ in arrs],
-            out_specs=[pl.BlockSpec((1, bl), lambda i: (i, 0))
+            grid=(n // bl,),
+            in_specs=[spec for _ in arrs],
+            out_specs=[spec for _ in out_slots],
+            out_shape=[jax.ShapeDtypeStruct((N, n), dt)
                        for _ in out_slots],
-            out_shape=[jax.ShapeDtypeStruct((grid, bl), dt)
-                       for _ in out_slots],
-            interpret=interp,
-        )(*[x.reshape(grid, bl) for x in arrs])
-        return [o.reshape(n) for o in outs]
+            interpret=interpret_mode(),
+        )(*arrs)
 
     return call
 
@@ -193,7 +209,6 @@ def fused_elementwise_call(program: Sequence[SlotOp],
                            out_slots: Sequence[int],
                            n_slots: Optional[int] = None,
                            block: int = 1024,
-                           interpret: Optional[bool] = None,
                            batched: bool = False,
                            cache: Optional[KernelCache] = None,
                            ) -> List[jax.Array]:
@@ -204,8 +219,8 @@ def fused_elementwise_call(program: Sequence[SlotOp],
     one length and dtype (one SPM line width per program).
 
     With ``batched=True`` every input is ``(N, n)`` — N program instances
-    — and the call runs over an ``(N, n // block)`` grid: one compile and
-    ONE dispatch for the whole batch. Outputs come back ``(N, n)``.
+    — and the call covers the whole batch: one compile and ONE dispatch.
+    Outputs come back ``(N, n)``.
 
     With a :class:`KernelCache` the call goes through a jitted compiled
     executable cached on the program's structure and shapes — repeated
@@ -224,28 +239,26 @@ def fused_elementwise_call(program: Sequence[SlotOp],
                           + list(out_slots))
     if batched:
         arrs = [x.reshape(x.shape[0], -1) for _, x in inputs]
-        N = arrs[0].shape[0]
     else:
-        arrs = [jnp.ravel(x) for _, x in inputs]
-        N = None
-    n = arrs[0].shape[-1]
+        arrs = [jnp.ravel(x)[None] for _, x in inputs]
+    N, n = arrs[0].shape
     dt = arrs[0].dtype
     if any(x.shape[-1] != n for x in arrs):
         raise ValueError("input length mismatch in fused program")
-    bl = pick_block(n, block, align=8)
-    assert n % bl == 0, (n, bl)
+    bl = pick_block(n, block)
 
     in_slots = tuple(s for s, _ in inputs)
     out_slots = tuple(out_slots)
-    interp = INTERPRET if interpret is None else interpret
     if cache is None:
-        return _make_fused_caller(program, in_slots, out_slots, n_slots,
-                                  N, n, bl, dt, interp)(*arrs)
-    key = ("fused", program, in_slots, out_slots, n_slots, N, n, bl,
-           str(dt), interp)
-    fn = cache.get(key, lambda: jax.jit(_make_fused_caller(
-        program, in_slots, out_slots, n_slots, N, n, bl, dt, interp)))
-    return list(fn(*arrs))
+        outs = _make_fused_caller(program, in_slots, out_slots, n_slots,
+                                  N, n, bl, dt)(*arrs)
+    else:
+        key = ("fused", program, in_slots, out_slots, n_slots, N, n, bl,
+               str(dt))
+        fn = cache.get(key, lambda: jax.jit(_make_fused_caller(
+            program, in_slots, out_slots, n_slots, N, n, bl, dt)))
+        outs = fn(*arrs)
+    return list(outs) if batched else [o[0] for o in outs]
 
 
 # ---------------------------------------------------------------------------
@@ -261,8 +274,8 @@ _Key = Tuple[int, int, int]
 
 @register_backend("pallas")
 class PallasBackend(BackendBase):
-    """Executes KVI workloads on fused Pallas kernels (TPU, or CPU with
-    ``interpret=True`` — the default off-TPU).
+    """Executes KVI workloads on fused Pallas kernels: compiled on a TPU,
+    interpreted on the CPU (:func:`~repro.kernels.common.interpret_mode`).
 
     max_fused_ops / max_fused_inputs bound how much of the element-wise
     subgraph one ``pallas_call`` swallows (VMEM slot-file pressure);
@@ -279,12 +292,10 @@ class PallasBackend(BackendBase):
     measurement classes — recompile nothing. Per-call hit/miss deltas
     land in the result's ``meta['compile_cache']``."""
 
-    def __init__(self, interpret: Optional[bool] = None, block: int = 1024,
-                 max_fused_ops: int = MAX_FUSED_OPS,
+    def __init__(self, block: int = 1024, max_fused_ops: int = MAX_FUSED_OPS,
                  max_fused_inputs: int = MAX_FUSED_INPUTS,
                  passes=None, verify: bool = False,
                  kernel_cache: Optional[KernelCache] = None, obs=None):
-        self.interpret = INTERPRET if interpret is None else interpret
         self.block = block
         self.max_fused_ops = max_fused_ops
         self.max_fused_inputs = max_fused_inputs
@@ -296,7 +307,7 @@ class PallasBackend(BackendBase):
         self.kernel_cache = kernel_cache if kernel_cache is not None \
             else KernelCache()
         self.fused_calls = 0             # observability: pallas_call count
-        self.reduce_calls = 0           # vmapped reduction kernel launches
+        self.reduce_calls = 0           # batched reduction kernel launches
 
     # -- register-file helpers -------------------------------------------
     # regfile[rid] is (N, length): N batched program instances.
@@ -329,48 +340,38 @@ class PallasBackend(BackendBase):
                   for key, slot in region.inputs]
         outs = fused_elementwise_call(
             region.ops, inputs, [slot for _, slot in region.outputs],
-            n_slots=region.n_slots, block=self.block,
-            interpret=self.interpret, batched=True,
+            n_slots=region.n_slots, block=self.block, batched=True,
             cache=self.kernel_cache)
         self.fused_calls += 1
         for (key, _slot), v in zip(region.outputs, outs):
             self._set(regfile, key, v)
 
     # -- scalar reductions -------------------------------------------------
-    def _make_reducer(self, op: KviOp, scalar: int,
-                      interp: bool) -> Callable:
-        """A jit-cacheable vmapped reduction over the batch dimension
-        (scalar immediates are baked in — they are part of the cache
-        key)."""
-        from repro.kernels import kdotp as _kd
-        if op is KviOp.KVRED:
-            return jax.vmap(lambda x: _kd.kvred(x, interpret=interp))
-        if op is KviOp.KDOTP:
-            return jax.vmap(lambda x, y: _kd.kdotp(x, y, interpret=interp))
+    @staticmethod
+    def _make_reducer(op: KviOp, scalar: int) -> Callable:
+        """A jit-cacheable batched reduction: one kernel launch reduces
+        every row (scalar immediates are baked in — they are part of the
+        cache key)."""
+        if op in (KviOp.KVRED, KviOp.KDOTP):
+            return reduce_rows
         if op is KviOp.KDOTPPS:
-            return jax.vmap(lambda x, y: _kd.kdotpps(x, y, scalar,
-                                                     interpret=interp))
+            return lambda x, y: reduce_rows(x, y, shift=scalar)
         if op is KviOp.KSVADDRF:
-            return jax.vmap(lambda x: _kd.kvred(x, interpret=interp)
-                            + jnp.asarray(scalar, jnp.int32))
+            return lambda x: reduce_rows(x) + jnp.asarray(scalar, jnp.int32)
         if op is KviOp.KSVMULRF:
             # sum(a * s) == s * sum(a)  (mod 2^32 wrap arithmetic)
-            return jax.vmap(lambda x: _kd.kvred(x, interpret=interp)
-                            * jnp.asarray(scalar, jnp.int32))
+            return lambda x: reduce_rows(x) * jnp.asarray(scalar, jnp.int32)
         raise ValueError(op)             # pragma: no cover
 
     def _reduce(self, i: KviInstr, regfile):
-        """One vmapped reduction kernel over the whole batch: the batch
-        dimension becomes a vmap axis over the Pallas kdotp/kvred kernels
-        (one launch for N instances, compiled once per structure via the
-        kernel cache)."""
+        """One batched reduction kernel over the whole batch (one launch
+        for N instances, compiled once per structure via the kernel
+        cache)."""
         a = self._slice(regfile, (i.src1.id, i.src1.offset, i.length))
-        interp = self.interpret
         key = ("red", i.op.value, i.scalar, a.shape[0], i.length,
-               str(a.dtype), interp)
+               str(a.dtype))
         fn = self.kernel_cache.get(
-            key, lambda: jax.jit(self._make_reducer(i.op, i.scalar,
-                                                    interp)))
+            key, lambda: jax.jit(self._make_reducer(i.op, i.scalar)))
         if i.op in (KviOp.KDOTP, KviOp.KDOTPPS):
             b = self._slice(regfile, (i.src2.id, i.src2.offset, i.length))
             r = fn(a, b)
@@ -385,7 +386,7 @@ class PallasBackend(BackendBase):
                    ) -> List[Dict[str, np.ndarray]]:
         """Execute N structurally identical programs (different data) in
         one batched walk: every planned region is one ``pallas_call``
-        over a batch grid, every reduction one vmapped kernel."""
+        over a batch grid, every reduction one batched kernel."""
         proto = programs[0]
         N = len(programs)
         regfile = {r.id: jnp.zeros((N, r.length), np_dtype(r.elem_bytes))

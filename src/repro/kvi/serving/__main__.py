@@ -84,6 +84,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     backend = None
     if not args.no_backend:
         from repro.kvi.backend import get_backend
+        from repro.runtime.compile_cache import enable_compile_cache
+        enable_compile_cache()
         backend = get_backend("pallas", passes=(), obs=obs)
 
     engine = ServeEngine(templates, n_harts=args.harts, backend=backend,

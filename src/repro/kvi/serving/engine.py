@@ -113,6 +113,7 @@ class StepRecord:
     wave_size: int
     buckets: List[int] = field(default_factory=list)
     cache_misses: int = 0
+    pallas_calls: int = 0
 
 
 class ServeEngine:
@@ -123,9 +124,10 @@ class ServeEngine:
                 for schedule-only runs (tests, trace analysis — all
                 cycle-domain metrics still produced).
     batching  — ``False`` degrades every group to one-request-at-a-time
-                execution (the baseline the ≥2x benchmark gate compares
-                against). The virtual-time schedule is identical either
-                way; only wall-clock execution differs.
+                execution (the baseline the batching gate compares
+                against: more ``pallas_call``s per request). The
+                virtual-time schedule is identical either way; only the
+                dispatches differ.
     max_batch — compiled batch-shape cap (power of two).
     """
 
@@ -175,6 +177,7 @@ class ServeEngine:
                 programs, name=f"serve.{tpl.name}.s{step.step}x{size}")
             res = self.backend.run_workload(wl)
             step.cache_misses += res.meta["compile_cache"]["misses"]
+            step.pallas_calls += res.meta["pallas_calls"]
 
     def prewarm_buckets(self) -> float:
         """Ahead-of-time compile: run one throwaway batch per (template,
@@ -386,6 +389,10 @@ class ServeEngine:
             if makespan else 0.0,
         }
         if self.backend is not None:
+            calls = sum(s.pallas_calls for s in self.steps)
+            throughput["pallas_calls"] = calls
+            throughput["pallas_calls_per_request"] = round(calls / n, 4) \
+                if n else 0.0
             throughput["execute_s"] = round(execute_s, 4)
             throughput["prewarm_s"] = round(prewarm_s, 4)
             throughput["req_per_s"] = round(n / execute_s, 2) \

@@ -112,7 +112,8 @@ def _build_program(kernel: str, elem_bytes: int, smoke: bool,
                    seed: int) -> KviProgram:
     from repro.kvi.programs import (conv2d_program, fft_program,
                                     matmul_program)
-    S, n_fft, m = (8, 32, 8) if smoke else (16, 64, 16)
+    # full sizes are the paper's: conv 32x32, FFT-256, matmul 64x64
+    S, n_fft, m = (8, 32, 8) if smoke else (32, 256, 64)
     # stable per-kernel stream id (str hash is process-randomized)
     kid = {"conv": 1, "fft": 2, "matmul": 3}.get(kernel, 0)
     rng = np.random.default_rng((seed, kid, elem_bytes))

@@ -10,13 +10,13 @@ touches jax device state (smoke tests must keep seeing 1 CPU device).
 from __future__ import annotations
 
 import jax
-import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(shape=None, axes=("data", "model")):
@@ -24,7 +24,14 @@ def make_host_mesh(shape=None, axes=("data", "model")):
     n = len(jax.devices())
     if shape is None:
         shape = (n, 1) if len(axes) == 2 else (n,)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape, axes):
+    # the models place activations with with_sharding_constraint and let
+    # the partitioner propagate the rest, which needs Auto axes
+    # (jax.make_mesh defaults to Explicit)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 # TPU v5e-class hardware constants used by the roofline analysis.

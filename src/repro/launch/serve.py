@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import List
 
 import numpy as np
 import jax
@@ -14,10 +15,13 @@ import jax
 from repro.configs import get_spec, reduced_model
 from repro.models import model_zoo as zoo
 from repro.models import params as params_lib
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving.engine import Request, ServingEngine
 
 
-def main(argv=None) -> int:
+def run(argv=None) -> List[Request]:
+    """Serve a seeded random request stream; returns the finished
+    requests (with their greedy ``out_tokens``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--reduced", action="store_true")
@@ -27,6 +31,7 @@ def main(argv=None) -> int:
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     spec = get_spec(args.arch)
     cfg = reduced_model(spec.model) if args.reduced else spec.model
@@ -49,6 +54,11 @@ def main(argv=None) -> int:
           f"({total_new / dt:.1f} tok/s), "
           f"TTFT p50={np.percentile(ttfts, 50):.2f}s "
           f"p99={np.percentile(ttfts, 99):.2f}s")
+    return done
+
+
+def main(argv=None) -> int:
+    run(argv)
     return 0
 
 

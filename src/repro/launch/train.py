@@ -29,6 +29,7 @@ from repro.models import params as params_lib
 from repro.models import steps as steps_lib
 from repro.models.sharding import make_rules
 from repro.optim.optimizer import OptimizerConfig, adamw_init
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault_tolerance import PreemptionGuard, StragglerDetector
 
 
@@ -67,6 +68,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg, par, shape, rules, train_step, data, opt_cfg = build_trainer(
         args.arch, reduced=args.reduced, seq=args.seq, batch=args.batch,

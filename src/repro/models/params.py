@@ -11,6 +11,7 @@ keeping shapes, shardings and init in lockstep by construction.
 """
 from __future__ import annotations
 
+import zlib
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -92,7 +93,9 @@ def initialize(template, rng):
     out = []
     for path, p in leaves:
         name = "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-        key = jax.random.fold_in(rng, hash(name) % (2**31))
+        # crc32, not hash(): str hashes change from process to process,
+        # and the same seed must give the same weights in every run
+        key = jax.random.fold_in(rng, zlib.crc32(name.encode()) % (2**31))
         out.append(_init_leaf(p, key))
     return jax.tree_util.tree_unflatten(treedef, out)
 

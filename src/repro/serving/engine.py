@@ -115,7 +115,12 @@ class ServingEngine:
                 tokens[s, 0] = req.out_tokens[-1] if req.out_tokens else 0
         logits, self.cache = self._decode(self.params, self.cache,
                                           {"tokens": jnp.asarray(tokens)})
-        next_tok = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+        last = logits[:, -1]
+        next_tok, finite = jax.device_get(
+            (jnp.argmax(last, axis=-1), jnp.isfinite(last).all()))
+        if not finite:
+            raise FloatingPointError("decode step produced non-finite "
+                                     "logits")
         now = time.monotonic()
         done_slots = []
         for s, req in self.active.items():

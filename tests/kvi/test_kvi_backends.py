@@ -7,10 +7,17 @@ executors, one assertion (the point of the unified IR).
   * CycleSim timing must satisfy the paper invariant
     sym-MIMD cycles <= het-MIMD cycles <= shared cycles.
 """
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
 import numpy as np
 import pytest
 
 from _hypothesis_compat import given, settings, st
+from repro.kernels.common import interpret_mode
 from repro.core.programs import conv2d_oracle
 from repro.core.simulator import SimResult
 from repro.kvi import KviProgramBuilder, get_backend
@@ -18,6 +25,9 @@ from repro.kvi.programs import (conv2d_program, conv2d_result, fft_program,
                                 fft_result, matmul_program, matmul_result)
 
 BACKENDS = ("oracle", "cyclesim", "pallas")
+
+
+SRC = str(Path(__file__).resolve().parents[2] / "src")
 
 
 def run_all(prog):
@@ -188,3 +198,37 @@ def test_random_elementwise_programs_agree(ops, seed):
         a = res["oracle"].outputs[o]
         assert np.array_equal(a, res["cyclesim"].outputs[o]), o
         assert np.array_equal(a, res["pallas"].outputs[o]), o
+
+
+# ---------------------------------------------------------------------------
+# Platform choice: made at call time, in one place.
+# ---------------------------------------------------------------------------
+
+
+class TestPlatform:
+    def test_import_and_cyclesim_initialise_no_jax_backend(self):
+        # the DSE's spawn workers only import the registry and run the
+        # cycle model; none of them may open the chip's runtime
+        code = ("import sys\n"
+                "import repro.kvi\n"
+                "from repro.kvi import get_backend\n"
+                "get_backend('cyclesim')\n"
+                "assert 'repro.kvi.pallas_backend' in sys.modules\n"
+                "from jax._src import xla_bridge\n"
+                "sys.exit(int(xla_bridge.backends_are_initialized()))\n")
+        p = subprocess.run([sys.executable, "-c", code],
+                           env=dict(os.environ, PYTHONPATH=SRC),
+                           capture_output=True, text=True, timeout=120)
+        assert p.returncode == 0, p.stderr[-2000:]
+
+    @pytest.mark.parametrize("platform,interpret",
+                             [("tpu", False), ("cpu", True)])
+    def test_interpret_mode_follows_platform(self, monkeypatch, platform,
+                                             interpret):
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        assert interpret_mode() is interpret
+
+    def test_interpret_mode_raises_on_unknown_platform(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            interpret_mode()

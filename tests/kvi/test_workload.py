@@ -332,8 +332,7 @@ class TestDeprecationShims:
         x = jnp.arange(-8, 8, dtype=jnp.int32)
         with pytest.warns(DeprecationWarning, match="KviProgramBuilder"):
             out = run_vops([("ksvmulsc", 1, 0, None, 3),
-                            ("krelu", 1, 1, None, 0)], [x],
-                           interpret=True)
+                            ("krelu", 1, 1, None, 0)], [x])
         want = np.maximum(np.arange(-8, 8) * 3, 0).astype(np.int32)
         assert np.array_equal(np.asarray(out), want)
 

@@ -19,8 +19,9 @@ SCRIPT = textwrap.dedent("""
     from repro.launch.compile import (build_cell, estimate_device_memory,
                                       estimate_hbm_traffic, lower_cell)
     from repro.launch.hlo_analysis import analyze_hlo
+    from repro.launch.mesh import make_host_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh((2, 4))
     arch, shape = sys.argv[1], sys.argv[2]
     cell = build_cell(arch, shape, mesh)
     lowered, _ = lower_cell(cell)

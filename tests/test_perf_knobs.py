@@ -96,7 +96,7 @@ def test_pure_dp_rules():
     from jax.sharding import AbstractMesh, PartitionSpec as PS
     from repro.models.sharding import make_rules
     spec = get_spec("llama3.2-1b")
-    mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     r = make_rules(mesh, spec.model, spec.parallelism.replace(pure_dp=True))
     assert r.spec(("batch", "seq"), (256, 4096)) == PS(("data", "model"), None)
     assert r.mapping["heads"] is None and r.mapping["mlp"] is None

@@ -79,3 +79,28 @@ def test_greedy_matches_decode_loop(small_engine_parts, rng):
         logits, cache = decode(params, cache,
                                {"tokens": jnp.asarray([[nxt]], jnp.int32)})
     assert got == out
+
+
+def test_seeded_weights_identical_across_processes():
+    """The same seed gives the same weights in every process, whatever
+    PYTHONHASHSEED is (str hashes are randomised per process)."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    code = ("import jax, numpy as np\n"
+            "from repro.configs import get_spec, reduced_model\n"
+            "from repro.models import model_zoo as zoo, params\n"
+            "cfg = reduced_model(get_spec('llama3.2-1b').model)\n"
+            "p = params.initialize(zoo.param_template(cfg),\n"
+            "                      jax.random.PRNGKey(0))\n"
+            "print(sum(float(np.abs(np.asarray(x, np.float64)).sum())\n"
+            "          for x in jax.tree_util.tree_leaves(p)))\n")
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    sums = [subprocess.run([sys.executable, "-c", code], check=True,
+                           capture_output=True, text=True, timeout=300,
+                           env=dict(os.environ, PYTHONPATH=src,
+                                    PYTHONHASHSEED=seed)).stdout
+            for seed in ("1", "2")]
+    assert sums[0] == sums[1], sums

@@ -6,9 +6,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as PS
 from repro.configs import get_spec
 from repro.models.sharding import Rules, make_rules
 
-# jax >= 0.4.36: AbstractMesh takes one ((name, size), ...) shape tuple
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_POD = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def rules_for(arch, mesh=MESH):
