@@ -11,6 +11,11 @@ parameter (default off, zero overhead). When enabled it collects:
   * a :class:`~repro.kvi.obs.metrics.MetricsRegistry` — counters,
     gauges and exact-bucket histograms behind one ``snapshot()``.
 
+Wall time on the served path is not in the bundle: ``PallasBackend``
+and ``ServeEngine`` mark it with :func:`~repro.kvi.obs.host.host_span`,
+which writes ``kvi.*`` spans into the JAX profiler's trace, on the
+device trace's clock, whenever a profiler session is running.
+
 ``python -m repro.kvi.obs view TRACE`` summarizes a saved trace (text
 timeline + top-k stall attribution); ``... validate TRACE`` checks it
 against the kvi-trace-v1 schema. The volatile-key scrubber every
@@ -20,6 +25,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+from repro.kvi.obs.host import host_span  # noqa: F401
 from repro.kvi.obs.metrics import (NULL_METRICS, Counter, Gauge,  # noqa: F401
                                    Histogram, MetricsRegistry,
                                    NullMetrics, validate_metrics)

@@ -13,7 +13,8 @@ Two clock domains coexist, as separate tracks:
     and request flows land at exact simulated times, byte-reproducible
     under a fixed seed.
   * **wall**   — real seconds since tracer construction, for the layers
-    with no virtual clock (Pallas compile/execute, DSE point walltime).
+    with no virtual clock (DSE point walltime; the served path's wall
+    time is in the JAX profiler's trace, :mod:`repro.kvi.obs.host`).
     Wall tracks are volatile by nature; :func:`canonical_trace` drops
     them (and scrubs wall argument fields) so determinism gates can
     byte-compare what remains.

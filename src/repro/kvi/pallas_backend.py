@@ -43,6 +43,8 @@ from repro.kernels.kdotp import reduce_rows
 from repro.kvi.backend import (BackendBase, BackendResult, register_backend)
 from repro.kvi.ir import (ELEMWISE_OPS, KviInstr, KviOp, KviProgram,
                           ScalarBlock, np_dtype)
+from repro.kvi.obs import host
+from repro.kvi.obs.host import host_span
 from repro.kvi.passes.fusion import (MAX_FUSED_INPUTS, MAX_FUSED_OPS,
                                      META_KEY, FusedRegion, FusionPlan,
                                      plan_fusion_regions)
@@ -301,13 +303,16 @@ class PallasBackend(BackendBase):
         self.max_fused_inputs = max_fused_inputs
         self.passes = passes
         self.verify = verify
-        # optional telemetry bundle (repro.kvi.obs.Obs): wall-domain
-        # spans per run_workload + compile-cache / dispatch counters
+        # optional telemetry bundle (repro.kvi.obs.Obs): run, dispatch
+        # and compile-cache counters (wall time is in the profiler's
+        # trace, under the kvi.* host spans)
         self.obs = obs
         self.kernel_cache = kernel_cache if kernel_cache is not None \
             else KernelCache()
         self.fused_calls = 0             # observability: pallas_call count
         self.reduce_calls = 0           # batched reduction kernel launches
+        self.host_syncs = 0             # kmemstr device->host copies
+        self.eager_ops = 0              # kmemld/kvcp/reductions, one each
 
     # -- register-file helpers -------------------------------------------
     # regfile[rid] is (N, length): N batched program instances.
@@ -389,11 +394,13 @@ class PallasBackend(BackendBase):
         over a batch grid, every reduction one batched kernel."""
         proto = programs[0]
         N = len(programs)
-        regfile = {r.id: jnp.zeros((N, r.length), np_dtype(r.elem_bytes))
-                   for r in proto.vregs}
-        mem = {m.id: np.stack([np.asarray(p.mem_init[m.id]).reshape(-1)
-                               for p in programs])
-               for m in proto.mems}
+        with host_span(host.WALK_STAGE):
+            regfile = {r.id: jnp.zeros((N, r.length),
+                                       np_dtype(r.elem_bytes))
+                       for r in proto.vregs}
+            mem = {m.id: np.stack([np.asarray(p.mem_init[m.id]).reshape(-1)
+                                   for p in programs])
+                   for m in proto.mems}
         plan = self._plan(proto)
         region_at = {r.items[0]: r for r in plan.regions}
         fused = plan.member_items()
@@ -403,35 +410,48 @@ class PallasBackend(BackendBase):
                 continue                 # no timing model here
             region = region_at.get(idx)
             if region is not None:
-                self._run_region(region, regfile)
+                with host_span(host.WALK_REGION):
+                    self._run_region(region, regfile)
                 continue
             if idx in fused:
                 continue                 # executed with its region head
             i: KviInstr = it
             if i.op is KviOp.KMEMLD:
-                arr = mem[i.src1.id]
-                # Mfu semantics: the whole buffer lands in the scratchpad
-                self._set(regfile, (i.dst.id, i.dst.offset, arr.shape[1]),
-                          jnp.asarray(arr, np_dtype(i.elem_bytes)))
+                with host_span(host.WALK_LOAD):
+                    arr = mem[i.src1.id]
+                    # Mfu semantics: the whole buffer lands in the
+                    # scratchpad
+                    self._set(regfile,
+                              (i.dst.id, i.dst.offset, arr.shape[1]),
+                              jnp.asarray(arr, np_dtype(i.elem_bytes)))
+                self.eager_ops += 1
             elif i.op is KviOp.KMEMSTR:
-                v = self._slice(regfile,
-                                (i.src1.id, i.src1.offset, i.length))
-                mem[i.dst.id] = np.asarray(v)
+                with host_span(host.WALK_SYNC):
+                    v = self._slice(regfile,
+                                    (i.src1.id, i.src1.offset, i.length))
+                    mem[i.dst.id] = np.asarray(v)
+                self.host_syncs += 1
             elif i.op is KviOp.KVCP:
-                v = self._slice(regfile,
-                                (i.src1.id, i.src1.offset, i.length))
-                self._set(regfile, (i.dst.id, i.dst.offset, i.length), v)
+                with host_span(host.WALK_COPY):
+                    v = self._slice(regfile,
+                                    (i.src1.id, i.src1.offset, i.length))
+                    self._set(regfile, (i.dst.id, i.dst.offset, i.length),
+                              v)
+                self.eager_ops += 1
             else:
-                self._reduce(i, regfile)
+                with host_span(host.WALK_REDUCE):
+                    self._reduce(i, regfile)
+                self.eager_ops += 1
 
         results = []
-        for b in range(N):
-            outputs = {}
-            for m in programs[b].outputs:
-                shape = programs[b].mem_init[m.id].shape
-                outputs[m.name] = np.asarray(mem[m.id][b]
-                                             ).reshape(shape).copy()
-            results.append(outputs)
+        with host_span(host.WALK_OUTPUTS):
+            for b in range(N):
+                outputs = {}
+                for m in programs[b].outputs:
+                    shape = programs[b].mem_init[m.id].shape
+                    outputs[m.name] = np.asarray(mem[m.id][b]
+                                                 ).reshape(shape).copy()
+                results.append(outputs)
         return results
 
     def run_workload(self, workload: KviWorkload,
@@ -442,48 +462,53 @@ class PallasBackend(BackendBase):
         TPU the batch grid IS the hart-level parallelism.
 
         ``meta`` reports the run's observability: structural ``groups``,
-        issued ``pallas_calls``, this call's kernel-cache hit/miss deltas
+        issued ``pallas_calls``, ``host_syncs`` (``kmemstr`` device->host
+        copies), ``eager_ops`` (``kmemld``, ``kvcp`` and reductions, each
+        dispatched on its own), this call's kernel-cache hit/miss deltas
         (``compile_cache``) and ``wall_s`` — the real execution walltime
         (outputs are materialized to numpy inside the walk, so the clock
         covers compile + dispatch + compute, not an async handle). The
-        DSE walltime axis and the serving engine read these directly."""
+        DSE walltime axis and the serving engine read these directly.
+        Inside a profiler session the call, its preparation and each
+        walk item are ``kvi.*`` host spans (:mod:`repro.kvi.obs.host`)."""
         t0 = time.perf_counter()
-        workload = self.optimize_workload(workload, verify=verify)
-        calls_before = self.fused_calls + self.reduce_calls
-        cc_before = (self.kernel_cache.hits, self.kernel_cache.misses)
-        groups: Dict[tuple, List[int]] = {}
-        for idx, e in enumerate(workload.entries):
-            groups.setdefault(structural_signature(e.program),
-                              []).append(idx)
-        entry_outputs: List[Optional[Dict[str, np.ndarray]]] = \
-            [None] * len(workload.entries)
-        for idxs in groups.values():
-            outs = self._run_batch(
-                [workload.entries[i].program for i in idxs])
-            for i, out in zip(idxs, outs):
-                entry_outputs[i] = out
-        results = tuple(BackendResult(self.name, out)
-                        for out in entry_outputs)
-        calls = self.fused_calls + self.reduce_calls - calls_before
-        cc = {"hits": self.kernel_cache.hits - cc_before[0],
-              "misses": self.kernel_cache.misses - cc_before[1]}
+        with host_span(host.RUN_WORKLOAD,
+                       entries=len(workload.entries)) as span:
+            with host_span(host.PREPARE):
+                workload = self.optimize_workload(workload, verify=verify)
+                groups: Dict[tuple, List[int]] = {}
+                for idx, e in enumerate(workload.entries):
+                    groups.setdefault(structural_signature(e.program),
+                                      []).append(idx)
+            span.set_metadata(groups=len(groups))
+            calls0 = self.fused_calls + self.reduce_calls
+            syncs0, eager0 = self.host_syncs, self.eager_ops
+            cc0 = (self.kernel_cache.hits, self.kernel_cache.misses)
+            entry_outputs: List[Optional[Dict[str, np.ndarray]]] = \
+                [None] * len(workload.entries)
+            for idxs in groups.values():
+                with host_span(host.WALK, N=len(idxs),
+                               workload=workload.name):
+                    outs = self._run_batch(
+                        [workload.entries[i].program for i in idxs])
+                for i, out in zip(idxs, outs):
+                    entry_outputs[i] = out
+            results = tuple(BackendResult(self.name, out)
+                            for out in entry_outputs)
+        calls = self.fused_calls + self.reduce_calls - calls0
+        cc = {"hits": self.kernel_cache.hits - cc0[0],
+              "misses": self.kernel_cache.misses - cc0[1]}
         wall_s = round(time.perf_counter() - t0, 6)
         if self.obs is not None and self.obs.enabled:
-            tr = self.obs.tracer
-            start_us = tr.wall_us() - wall_s * 1e6
-            tr.span(("pallas", "run_workload"), "run_workload",
-                    round(max(0.0, start_us), 3), round(wall_s * 1e6, 3),
-                    cat="wall", clock="wall",
-                    args={"entries": len(workload.entries),
-                          "groups": len(groups), "pallas_calls": calls})
             m = self.obs.metrics
             m.counter("pallas.runs").inc()
             m.counter("pallas.calls").inc(calls)
             m.absorb("pallas.compile_cache", cc)
-            m.histogram("pallas.run_wall_s").observe(wall_s)
         return WorkloadResult(
             self.name, workload, results,
             meta={"groups": len(groups),
                   "pallas_calls": calls,
+                  "host_syncs": self.host_syncs - syncs0,
+                  "eager_ops": self.eager_ops - eager0,
                   "compile_cache": cc,
                   "wall_s": wall_s})

@@ -42,6 +42,8 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from repro.kvi.lowering import TraceCache
+from repro.kvi.obs import host
+from repro.kvi.obs.host import host_span
 # the volatile-key set and scrubber live in the shared obs layer now;
 # SERVE_VOLATILE stays importable from here for backwards compatibility
 from repro.kvi.obs.scrub import SERVE_VOLATILE, scrub  # noqa: F401
@@ -145,8 +147,9 @@ class ServeEngine:
         self.seed = seed
         self.prewarm = prewarm
         # optional telemetry bundle (repro.kvi.obs.Obs): request flows,
-        # step/wall spans and latency metrics; shared with the scheduler
-        # so ticket spans land in the same trace
+        # step spans and latency metrics; shared with the scheduler so
+        # ticket spans land in the same trace (wall time is in the
+        # profiler's trace, under the kvi.engine.* host spans)
         self.obs = obs
         self.scheduler = HartScheduler(
             n_harts=n_harts,
@@ -171,10 +174,11 @@ class ServeEngine:
             step.buckets.append(size)
             if self.backend is None:
                 continue
-            programs = [r.template.instantiate(self.seed, r.rid)
-                        for r in chunk]
-            wl = KviWorkload.homogeneous(
-                programs, name=f"serve.{tpl.name}.s{step.step}x{size}")
+            with host_span(host.ENGINE_INSTANTIATE):
+                programs = [r.template.instantiate(self.seed, r.rid)
+                            for r in chunk]
+                wl = KviWorkload.homogeneous(
+                    programs, name=f"serve.{tpl.name}.s{step.step}x{size}")
             res = self.backend.run_workload(wl)
             step.cache_misses += res.meta["compile_cache"]["misses"]
             step.pallas_calls += res.meta["pallas_calls"]
@@ -207,7 +211,13 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def run(self, specs: Sequence[RequestSpec]) -> Dict[str, object]:
         """Serve the whole arrival stream; returns the report dict
-        (see :meth:`report`)."""
+        (see :meth:`report`). Inside a profiler session the run, each
+        step's admission, each bucket's instantiation and the report are
+        ``kvi.engine.*`` host spans (:mod:`repro.kvi.obs.host`)."""
+        with host_span(host.ENGINE_RUN):
+            return self._run(specs)
+
+    def _run(self, specs: Sequence[RequestSpec]) -> Dict[str, object]:
         t_engine = time.perf_counter()
         obs_on = self.obs is not None and self.obs.enabled
         req_base = len(self.requests)    # flow-id offset across runs
@@ -221,11 +231,7 @@ class ServeEngine:
                     f"request {rid} wants template {s.template_key!r}; "
                     f"engine serves {sorted(self.templates)}")
             reqs.append(ServedRequest(rid, s, tpl))
-        pw_start = self.obs.tracer.wall_us() if obs_on else 0.0
         prewarm_s = self.prewarm_buckets() if self.prewarm else 0.0
-        if obs_on and prewarm_s:
-            self.obs.tracer.wall_span(("serving", "wall"), "prewarm",
-                                      pw_start)
 
         execute_s = 0.0
         i = 0
@@ -236,30 +242,27 @@ class ServeEngine:
             if reqs[i].spec.t > now:
                 # machine idle until the next arrival
                 now = reqs[i].spec.t
-            wave = []
-            while i < len(reqs) and reqs[i].spec.t <= now:
-                wave.append(reqs[i])
-                i += 1
-            step = StepRecord(step_no, now, len(wave))
-            # continuous admission: earliest-finish-first, arrival order
-            for r in wave:
-                r.ticket = sched.admit(r.template.program, now=now,
-                                       est=r.template.est_cycles)
-                r.step = step_no
-            # signature batching: one homogeneous batch per template
-            groups: Dict[str, List[ServedRequest]] = {}
-            for r in wave:
-                groups.setdefault(r.template.name, []).append(r)
+            with host_span(host.ENGINE_ADMIT):
+                wave = []
+                while i < len(reqs) and reqs[i].spec.t <= now:
+                    wave.append(reqs[i])
+                    i += 1
+                step = StepRecord(step_no, now, len(wave))
+                # continuous admission: earliest-finish-first, arrival
+                # order
+                for r in wave:
+                    r.ticket = sched.admit(r.template.program, now=now,
+                                           est=r.template.est_cycles)
+                    r.step = step_no
+                # signature batching: one homogeneous batch per template
+                groups: Dict[str, List[ServedRequest]] = {}
+                for r in wave:
+                    groups.setdefault(r.template.name, []).append(r)
             t0 = time.perf_counter()
-            ex_start = self.obs.tracer.wall_us() if obs_on else 0.0
             for name in sorted(groups):
                 self._execute_group(self.templates[name], groups[name],
                                     step)
             execute_s += time.perf_counter() - t0
-            if obs_on and self.backend is not None and groups:
-                self.obs.tracer.wall_span(
-                    ("serving", "wall"), f"execute.step{step_no}",
-                    ex_start, args={"wave": len(wave)})
             self.steps.append(step)
             step_no += 1
             if i < len(reqs):
@@ -267,10 +270,11 @@ class ServeEngine:
                 # frees; arrivals in between accumulate into the wave
                 now = max(now, min(sched.hart_free))
         self.requests.extend(reqs)
-        report = self.report(prewarm_s=prewarm_s, execute_s=execute_s,
-                             engine_s=time.perf_counter() - t_engine)
-        if obs_on:
-            self._emit_telemetry(reqs, req_base, step_base, report)
+        with host_span(host.ENGINE_REPORT):
+            report = self.report(prewarm_s=prewarm_s, execute_s=execute_s,
+                                 engine_s=time.perf_counter() - t_engine)
+            if obs_on:
+                self._emit_telemetry(reqs, req_base, step_base, report)
         return report
 
     def _emit_telemetry(self, reqs: List[ServedRequest], req_base: int,
