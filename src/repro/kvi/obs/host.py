@@ -11,8 +11,9 @@ switch on or off.
 
 Every span name is a constant here, so this module is the one list of
 names a trace reader matches against (all start with ``kvi.``).
-Per-instruction spans carry no arguments; the per-call and per-batch
-spans do.
+The walk is one compiled device program, so no span marks a single
+instruction: a span inside the traced body would fire only while it is
+traced.
 """
 from __future__ import annotations
 
@@ -25,14 +26,12 @@ PREFIX = "kvi."
 # PallasBackend.run_workload
 RUN_WORKLOAD = "kvi.backend.run_workload"   # the whole call
 PREPARE = "kvi.backend.prepare"             # optimize + structural grouping
-# PallasBackend._run_batch: one batched walk and its items
+# PallasBackend._run_batch: one batched walk, one compiled call
 WALK = "kvi.walk"
-WALK_STAGE = "kvi.walk.stage"               # register file + memory staging
-WALK_LOAD = "kvi.walk.load"                 # one kmemld (eager set)
-WALK_REGION = "kvi.walk.region"             # one fused region's pallas_call
-WALK_COPY = "kvi.walk.copy"                 # one kvcp (eager set)
-WALK_REDUCE = "kvi.walk.reduce"             # one reduction kernel
-WALK_SYNC = "kvi.walk.sync"                 # one kmemstr: device->host copy
+WALK_BUILD = "kvi.walk.build"               # trace + compile (first batch)
+WALK_STAGE = "kvi.walk.stage"               # stack the loaded buffers
+WALK_CALL = "kvi.walk.call"                 # dispatch the compiled walk
+WALK_SYNC = "kvi.walk.sync"                 # its one device->host fetch
 WALK_OUTPUTS = "kvi.walk.outputs"           # per-request output copies
 # ServeEngine
 ENGINE_RUN = "kvi.engine.run"               # the whole run()
@@ -40,9 +39,9 @@ ENGINE_ADMIT = "kvi.engine.admit"           # one step's admission + grouping
 ENGINE_INSTANTIATE = "kvi.engine.instantiate"  # one bucket's programs
 ENGINE_REPORT = "kvi.engine.report"         # report() + telemetry
 
-SPANS = (RUN_WORKLOAD, PREPARE, WALK, WALK_STAGE, WALK_LOAD, WALK_REGION,
-         WALK_COPY, WALK_REDUCE, WALK_SYNC, WALK_OUTPUTS, ENGINE_RUN,
-         ENGINE_ADMIT, ENGINE_INSTANTIATE, ENGINE_REPORT)
+SPANS = (RUN_WORKLOAD, PREPARE, WALK, WALK_BUILD, WALK_STAGE, WALK_CALL,
+         WALK_SYNC, WALK_OUTPUTS, ENGINE_RUN, ENGINE_ADMIT,
+         ENGINE_INSTANTIATE, ENGINE_REPORT)
 
 _NO_SPAN = contextlib.nullcontext()
 

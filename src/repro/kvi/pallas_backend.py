@@ -16,8 +16,10 @@ plan (``passes=()``) or one planned under different slot-file bounds.
 Workload batching: a homogeneous :class:`~repro.kvi.workload.KviWorkload`
 (N data instances of one program structure) executes as one **batch** —
 every fused segment is ONE ``pallas_call`` over ``(N, n)`` tiles and every
-reduction is one batched kernel launch, so N instances cost one compile
-and one dispatch per segment instead of N.
+reduction is one batched kernel launch. The whole walk — regions,
+reductions and register-file moves — is traced into one jitted device
+program per (structure, N) (:class:`CompiledWalk`), so a batch costs one
+dispatch and one device->host fetch, and the compile is paid once.
 Heterogeneous workloads are grouped by program structure and each group is
 batched the same way.
 
@@ -62,18 +64,17 @@ _UNSIGNED = {jnp.int8.dtype: jnp.uint8, jnp.int16.dtype: jnp.uint16,
 class KernelCache:
     """Compiled-call cache: slot-program structure -> a ``jax.jit``-wrapped
     callable closing over its ``pl.pallas_call`` (or batched reduction
-    kernel). Keys carry everything baked into the trace — the op/slot
-    program, batch shape, block split and dtype — so a hit is exactly a
-    compiled executable reuse.
+    kernel), and program structure -> its batch's :class:`CompiledWalk`,
+    which calls those kernels. Keys carry everything baked into the
+    trace — the op/slot program, batch shape, block split and dtype — so
+    a hit is exactly a traced function's or compiled executable's reuse.
 
-    An eager interpret-mode ``pallas_call`` re-traces on every invocation
-    (~100 ms for even a tiny fused segment); a warm jitted call costs tens
-    of microseconds. Scoped to a :class:`PallasBackend` instance by
-    default, so repeated ``run_workload`` calls — the serving engine's
-    steady-state traffic, the DSE's warm-up iterations — pay zero
-    recompiles; pass one cache to several backends to share it wider.
+    Scoped to a :class:`PallasBackend` instance by default, so repeated
+    ``run_workload`` calls — the serving engine's steady-state traffic,
+    the DSE's warm-up iterations — pay zero recompiles; pass one cache
+    to several backends to share it wider.
 
-    ``misses`` counts builds (compiles), ``hits`` compiled-call reuses.
+    ``misses`` counts builds, ``hits`` reuses: a warm batch is one hit.
     """
 
     hits: int = 0
@@ -267,11 +268,39 @@ def fused_elementwise_call(program: Sequence[SlotOp],
 # Whole-program executor: walks a KviProgram, executing the planned
 # FusedRegions. The walk is batched: the register file and main memory
 # carry a leading batch dimension of N program instances sharing one
-# structure.
+# structure. The whole walk is traced into ONE jitted function per
+# (structure, N), compiled once and called once per batch.
 # ---------------------------------------------------------------------------
 
 # a slot key: one (vreg id, element offset, length) window
 _Key = Tuple[int, int, int]
+
+
+@dataclass
+class CompiledWalk:
+    """One batched walk, compiled: ``fn(*staged)`` takes one ``(N, cols)``
+    array per entry of ``loads`` (its dtype, and the buffers read before
+    any store, concatenated in that order) and returns the stored output
+    buffers, concatenated per dtype; ``outputs`` maps each such buffer to
+    its ``(array, start, stop)`` columns. So a call is one host->device
+    transfer per dtype and one fetch. ``fused_calls`` / ``reduce_calls``
+    are the ``pallas_call``s one call issues. All but ``fn`` are filled
+    in while the walk is traced."""
+
+    loads: Tuple[Tuple[np.dtype, Tuple[int, ...]], ...]
+    outputs: Dict[int, Tuple[int, int, int]] = field(default_factory=dict)
+    fused_calls: int = 0
+    reduce_calls: int = 0
+    fn: Optional[Callable] = None
+
+
+def _columns(widths: Sequence[Tuple[int, int]]):
+    """``(id, width)`` pairs laid side by side: id -> (start, stop)."""
+    out, start = {}, 0
+    for mid, width in widths:
+        out[mid] = (start, start + width)
+        start += width
+    return out
 
 
 @register_backend("pallas")
@@ -286,13 +315,15 @@ class PallasBackend(BackendBase):
     ``fused_calls`` counts issued ``pallas_call``s — a batch of N
     homogeneous instances issues the same number as a single instance.
 
-    Every dispatch goes through an instance-scoped :class:`KernelCache`
-    (pass ``kernel_cache=`` to share one across backends): compiled
-    executables are keyed on slot-program structure + batch shape +
-    dtype, so repeated ``run_workload`` calls over the same program
-    structures — serving traffic, warm-up iterations, repeated DSE
-    measurement classes — recompile nothing. Per-call hit/miss deltas
-    land in the result's ``meta['compile_cache']``."""
+    Each batch runs as one :class:`CompiledWalk` held in an
+    instance-scoped :class:`KernelCache` (pass ``kernel_cache=`` to share
+    one across backends), keyed on the program structure, N, the fusion
+    bounds and the block: the walk's regions and reductions are nested
+    calls of the cache's per-kernel jitted callables, traced into one
+    device program. Repeated ``run_workload`` calls over the same
+    structures and batch sizes — serving traffic, warm-up iterations,
+    repeated DSE measurement classes — recompile nothing. Per-call
+    hit/miss deltas land in the result's ``meta['compile_cache']``."""
 
     def __init__(self, block: int = 1024, max_fused_ops: int = MAX_FUSED_OPS,
                  max_fused_inputs: int = MAX_FUSED_INPUTS,
@@ -311,8 +342,8 @@ class PallasBackend(BackendBase):
             else KernelCache()
         self.fused_calls = 0             # observability: pallas_call count
         self.reduce_calls = 0           # batched reduction kernel launches
-        self.host_syncs = 0             # kmemstr device->host copies
-        self.eager_ops = 0              # kmemld/kvcp/reductions, one each
+        self.host_syncs = 0             # device->host fetches, one per walk
+        self.walks_built = 0            # batched walks traced and compiled
 
     # -- register-file helpers -------------------------------------------
     # regfile[rid] is (N, length): N batched program instances.
@@ -322,9 +353,9 @@ class PallasBackend(BackendBase):
         return jax.lax.slice(r, (0, off), (r.shape[0], off + n))
 
     def _set(self, regfile, key: _Key, val):
-        rid, off, n = key
-        regfile[rid] = regfile[rid].at[:, off:off + n].set(
-            val.astype(regfile[rid].dtype))
+        rid, off, _ = key
+        regfile[rid] = jax.lax.dynamic_update_slice(
+            regfile[rid], val.astype(regfile[rid].dtype), (0, off))
 
     # -- fusion plan -------------------------------------------------------
     def _plan(self, program: KviProgram) -> FusionPlan:
@@ -347,7 +378,6 @@ class PallasBackend(BackendBase):
             region.ops, inputs, [slot for _, slot in region.outputs],
             n_slots=region.n_slots, block=self.block, batched=True,
             cache=self.kernel_cache)
-        self.fused_calls += 1
         for (key, _slot), v in zip(region.outputs, outs):
             self._set(regfile, key, v)
 
@@ -370,7 +400,7 @@ class PallasBackend(BackendBase):
 
     def _reduce(self, i: KviInstr, regfile):
         """One batched reduction kernel over the whole batch (one launch
-        for N instances, compiled once per structure via the kernel
+        for N instances, traced once per structure via the kernel
         cache)."""
         a = self._slice(regfile, (i.src1.id, i.src1.offset, i.length))
         key = ("red", i.op.value, i.scalar, a.shape[0], i.length,
@@ -382,95 +412,162 @@ class PallasBackend(BackendBase):
             r = fn(a, b)
         else:
             r = fn(a)
-        self.reduce_calls += 1
         self._set(regfile, (i.dst.id, i.dst.offset, 1),
                   jnp.reshape(r, (r.shape[0], 1)))
 
     # -- batched walk ------------------------------------------------------
-    def _run_batch(self, programs: Sequence[KviProgram]
-                   ) -> List[Dict[str, np.ndarray]]:
-        """Execute N structurally identical programs (different data) in
-        one batched walk: every planned region is one ``pallas_call``
-        over a batch grid, every reduction one batched kernel."""
-        proto = programs[0]
-        N = len(programs)
-        with host_span(host.WALK_STAGE):
-            regfile = {r.id: jnp.zeros((N, r.length),
-                                       np_dtype(r.elem_bytes))
-                       for r in proto.vregs}
-            mem = {m.id: np.stack([np.asarray(p.mem_init[m.id]).reshape(-1)
-                                   for p in programs])
-                   for m in proto.mems}
+    def _walk_fn(self, proto: KviProgram, N: int):
+        """The batched walk of ``proto``'s structure over N instances as
+        one traceable function: every planned region is one
+        ``pallas_call`` over the batch grid, every reduction one batched
+        kernel, ``kmemld``/``kvcp`` register-file updates, and
+        ``kmemstr`` replaces a buffer's traced contents.
+
+        Returns ``(walk, args, spec)``: the function, the shapes of its
+        staged arguments, and the :class:`CompiledWalk` it fills in as it
+        is traced (all but ``fn``)."""
         plan = self._plan(proto)
         region_at = {r.items[0]: r for r in plan.regions}
         fused = plan.member_items()
+        instrs = [(idx, it) for idx, it in enumerate(proto.items)
+                  if not isinstance(it, ScalarBlock)]
+        # the buffers read before any store: the walk's arguments, one
+        # staged array per dtype
+        loads: Dict[np.dtype, List[int]] = {}
+        seen, stored = set(), set()
+        for _, i in instrs:
+            if i.op is KviOp.KMEMLD and i.src1.id not in seen:
+                seen.add(i.src1.id)
+                dt = jax.dtypes.canonicalize_dtype(
+                    np.asarray(proto.mem_init[i.src1.id]).dtype)
+                loads.setdefault(dt, []).append(i.src1.id)
+            elif i.op is KviOp.KMEMSTR:
+                seen.add(i.dst.id)
+                stored.add(i.dst.id)
+        out_ids = [m.id for m in proto.outputs if m.id in stored]
+        spec = CompiledWalk(tuple((dt, tuple(mids))
+                                  for dt, mids in loads.items()))
+        in_cols = [_columns([(mid, proto.mem_init[mid].size)
+                             for mid in mids]) for mids in loads.values()]
 
-        for idx, it in enumerate(proto.items):
-            if isinstance(it, ScalarBlock):
-                continue                 # no timing model here
-            region = region_at.get(idx)
-            if region is not None:
-                with host_span(host.WALK_REGION):
+        def walk(*staged):
+            mem = {mid: jax.lax.slice(x, (0, c0), (N, c1))
+                   for x, cols in zip(staged, in_cols)
+                   for mid, (c0, c1) in cols.items()}
+            regfile = {r.id: jnp.zeros((N, r.length), np_dtype(r.elem_bytes))
+                       for r in proto.vregs}
+            for idx, i in instrs:
+                region = region_at.get(idx)
+                if region is not None:
                     self._run_region(region, regfile)
-                continue
-            if idx in fused:
-                continue                 # executed with its region head
-            i: KviInstr = it
-            if i.op is KviOp.KMEMLD:
-                with host_span(host.WALK_LOAD):
+                    spec.fused_calls += 1
+                elif idx in fused:
+                    continue             # executed with its region head
+                elif i.op is KviOp.KMEMLD:
                     arr = mem[i.src1.id]
                     # Mfu semantics: the whole buffer lands in the
                     # scratchpad
                     self._set(regfile,
                               (i.dst.id, i.dst.offset, arr.shape[1]),
-                              jnp.asarray(arr, np_dtype(i.elem_bytes)))
-                self.eager_ops += 1
-            elif i.op is KviOp.KMEMSTR:
-                with host_span(host.WALK_SYNC):
-                    v = self._slice(regfile,
-                                    (i.src1.id, i.src1.offset, i.length))
-                    mem[i.dst.id] = np.asarray(v)
-                self.host_syncs += 1
-            elif i.op is KviOp.KVCP:
-                with host_span(host.WALK_COPY):
+                              arr.astype(np_dtype(i.elem_bytes)))
+                elif i.op is KviOp.KMEMSTR:
+                    mem[i.dst.id] = self._slice(
+                        regfile, (i.src1.id, i.src1.offset, i.length))
+                elif i.op is KviOp.KVCP:
                     v = self._slice(regfile,
                                     (i.src1.id, i.src1.offset, i.length))
                     self._set(regfile, (i.dst.id, i.dst.offset, i.length),
                               v)
-                self.eager_ops += 1
-            else:
-                with host_span(host.WALK_REDUCE):
+                else:
                     self._reduce(i, regfile)
-                self.eager_ops += 1
+                    spec.reduce_calls += 1
+            # one array per dtype, so the host fetches the walk at once
+            by_dtype: Dict[str, List[int]] = {}
+            for mid in out_ids:
+                by_dtype.setdefault(str(mem[mid].dtype), []).append(mid)
+            outs = []
+            for k, mids in enumerate(by_dtype.values()):
+                cols = _columns([(mid, mem[mid].shape[1]) for mid in mids])
+                spec.outputs.update((mid, (k, c0, c1))
+                                    for mid, (c0, c1) in cols.items())
+                outs.append(jnp.concatenate([mem[mid] for mid in mids],
+                                            axis=1))
+            return tuple(outs)
+
+        args = [jax.ShapeDtypeStruct(
+            (N, sum(proto.mem_init[mid].size for mid in mids)), dt)
+            for dt, mids in loads.items()]
+        return walk, args, spec
+
+    def _build_walk(self, proto: KviProgram, N: int) -> CompiledWalk:
+        """Trace and compile the batched walk (:meth:`_walk_fn`)."""
+        walk, args, spec = self._walk_fn(proto, N)
+        spec.fn = jax.jit(walk).lower(*args).compile()
+        self.walks_built += 1
+        return spec
+
+    def _run_batch(self, programs: Sequence[KviProgram], signature: tuple
+                   ) -> List[Dict[str, np.ndarray]]:
+        """Execute N structurally identical programs (different data) in
+        one call of their compiled walk, built on the first batch of
+        this structure and N (``signature`` is the group's
+        :func:`~repro.kvi.workload.structural_signature`)."""
+        proto = programs[0]
+        N = len(programs)
+
+        def build() -> CompiledWalk:
+            with host_span(host.WALK_BUILD, N=N):
+                return self._build_walk(proto, N)
+        walk = self.kernel_cache.get(
+            ("walk", signature, N, self.max_fused_ops,
+             self.max_fused_inputs, self.block), build)
+        with host_span(host.WALK_STAGE):
+            staged = [np.stack([np.concatenate(
+                [np.asarray(p.mem_init[mid]).reshape(-1) for mid in mids])
+                for p in programs]).astype(dt, copy=False)
+                for dt, mids in walk.loads]
+        with host_span(host.WALK_CALL):
+            outs = walk.fn(*staged)
+        with host_span(host.WALK_SYNC):
+            outs = jax.device_get(outs)
+        self.host_syncs += 1
+        self.fused_calls += walk.fused_calls
+        self.reduce_calls += walk.reduce_calls
 
         results = []
         with host_span(host.WALK_OUTPUTS):
-            for b in range(N):
+            for b, p in enumerate(programs):
                 outputs = {}
-                for m in programs[b].outputs:
-                    shape = programs[b].mem_init[m.id].shape
-                    outputs[m.name] = np.asarray(mem[m.id][b]
-                                                 ).reshape(shape).copy()
+                for m in p.outputs:
+                    shape = p.mem_init[m.id].shape
+                    at = walk.outputs.get(m.id)
+                    # an output the walk never stores keeps its contents
+                    v = p.mem_init[m.id] if at is None \
+                        else outs[at[0]][b, at[1]:at[2]]
+                    outputs[m.name] = np.asarray(v).reshape(shape).copy()
                 results.append(outputs)
         return results
 
     def run_workload(self, workload: KviWorkload,
                      verify: Optional[bool] = None) -> WorkloadResult:
         """Group entries by program structure; each group runs as one
-        batched walk (one compile + one dispatch per fused segment for the
-        whole group). Hart assignments carry no timing meaning here — on
-        TPU the batch grid IS the hart-level parallelism.
+        call of its compiled batched walk (one compile per structure and
+        batch size, one dispatch and one device->host fetch per group).
+        Hart assignments carry no timing meaning here — on TPU the batch
+        grid IS the hart-level parallelism.
 
         ``meta`` reports the run's observability: structural ``groups``,
-        issued ``pallas_calls``, ``host_syncs`` (``kmemstr`` device->host
-        copies), ``eager_ops`` (``kmemld``, ``kvcp`` and reductions, each
-        dispatched on its own), this call's kernel-cache hit/miss deltas
-        (``compile_cache``) and ``wall_s`` — the real execution walltime
-        (outputs are materialized to numpy inside the walk, so the clock
-        covers compile + dispatch + compute, not an async handle). The
-        DSE walltime axis and the serving engine read these directly.
+        the ``walks`` run and the ``walks_built`` (traced and compiled)
+        in this call, the ``pallas_calls`` those walks issued,
+        ``host_syncs`` (device->host fetches, one per walk), this call's
+        kernel-cache hit/miss deltas (``compile_cache``: a warm walk is
+        one hit) and ``wall_s`` — the real execution walltime (outputs
+        are materialized to numpy inside the walk, so the clock covers
+        compile + dispatch + compute, not an async handle). The DSE
+        walltime axis and the serving engine read these directly.
         Inside a profiler session the call, its preparation and each
-        walk item are ``kvi.*`` host spans (:mod:`repro.kvi.obs.host`)."""
+        walk's build, staging, call, fetch and outputs are ``kvi.*``
+        host spans (:mod:`repro.kvi.obs.host`)."""
         t0 = time.perf_counter()
         with host_span(host.RUN_WORKLOAD,
                        entries=len(workload.entries)) as span:
@@ -482,20 +579,22 @@ class PallasBackend(BackendBase):
                                       []).append(idx)
             span.set_metadata(groups=len(groups))
             calls0 = self.fused_calls + self.reduce_calls
-            syncs0, eager0 = self.host_syncs, self.eager_ops
+            syncs0, built0 = self.host_syncs, self.walks_built
             cc0 = (self.kernel_cache.hits, self.kernel_cache.misses)
             entry_outputs: List[Optional[Dict[str, np.ndarray]]] = \
                 [None] * len(workload.entries)
-            for idxs in groups.values():
+            for signature, idxs in groups.items():
                 with host_span(host.WALK, N=len(idxs),
                                workload=workload.name):
                     outs = self._run_batch(
-                        [workload.entries[i].program for i in idxs])
+                        [workload.entries[i].program for i in idxs],
+                        signature)
                 for i, out in zip(idxs, outs):
                     entry_outputs[i] = out
             results = tuple(BackendResult(self.name, out)
                             for out in entry_outputs)
         calls = self.fused_calls + self.reduce_calls - calls0
+        built = self.walks_built - built0
         cc = {"hits": self.kernel_cache.hits - cc0[0],
               "misses": self.kernel_cache.misses - cc0[1]}
         wall_s = round(time.perf_counter() - t0, 6)
@@ -503,12 +602,15 @@ class PallasBackend(BackendBase):
             m = self.obs.metrics
             m.counter("pallas.runs").inc()
             m.counter("pallas.calls").inc(calls)
+            m.counter("pallas.walks").inc(len(groups))
+            m.counter("pallas.walks_built").inc(built)
             m.absorb("pallas.compile_cache", cc)
         return WorkloadResult(
             self.name, workload, results,
             meta={"groups": len(groups),
+                  "walks": len(groups),
+                  "walks_built": built,
                   "pallas_calls": calls,
                   "host_syncs": self.host_syncs - syncs0,
-                  "eager_ops": self.eager_ops - eager0,
                   "compile_cache": cc,
                   "wall_s": wall_s})

@@ -1,8 +1,11 @@
-"""The served path's host spans and counters: a batched walk under a
-``jax.profiler`` session leaves one ``kvi.walk.*`` span per walk item
-the program executes, nested in its ``kvi.walk``, and ``run_workload``'s
-``meta`` counts the same items; the engine's ``kvi.engine.*`` spans
-frame the backend's. Outputs stay bit-identical to the oracle."""
+"""The served path's host spans and counters: a batched walk is one
+compiled device program, so under a ``jax.profiler`` session it leaves
+one ``kvi.walk.call`` and one ``kvi.walk.sync`` nested in its
+``kvi.walk`` (and a ``kvi.walk.build`` only where it was compiled), and
+``run_workload``'s ``meta`` counts the walks, their builds, their one
+device->host fetch each and the ``pallas_call``s they issue; the
+engine's ``kvi.engine.*`` spans frame the backend's. Outputs stay
+bit-identical to the oracle."""
 import glob
 import os
 import subprocess
@@ -68,62 +71,81 @@ def _inside(inner, outer):
     return outer[1] <= inner[1] and inner[2] <= outer[2]
 
 
-def _planned_items(program):
-    """Each walk item kind the program executes, counted from its fusion
-    plan: a region per planned region, and every instruction outside the
-    regions by its opcode (a program with nothing to fuse carries no
-    plan)."""
+def _planned_calls(program):
+    """The ``pallas_call``s one walk of the program issues, from its
+    fusion plan: one per planned region, one per reduction outside the
+    regions (a program with nothing to fuse carries no plan)."""
     plan = program.meta.get(META_KEY) or plan_fusion_regions(program)
     fused = plan.member_items()
-    counts = {host.WALK_REGION: len(plan.regions), host.WALK_LOAD: 0,
-              host.WALK_SYNC: 0, host.WALK_COPY: 0, host.WALK_REDUCE: 0}
-    kind = {KviOp.KMEMLD: host.WALK_LOAD, KviOp.KMEMSTR: host.WALK_SYNC,
-            KviOp.KVCP: host.WALK_COPY}
-    for idx, it in enumerate(program.items):
-        if isinstance(it, ScalarBlock) or idx in fused:
-            continue
-        counts[kind.get(it.op, host.WALK_REDUCE)] += 1
-    return counts
+    moves = (KviOp.KMEMLD, KviOp.KMEMSTR, KviOp.KVCP)
+    return len(plan.regions), sum(
+        1 for idx, it in enumerate(program.items)
+        if not isinstance(it, ScalarBlock) and idx not in fused
+        and it.op not in moves)
 
 
-@pytest.mark.parametrize("build,kinds", [
-    (_conv, {host.WALK_REGION, host.WALK_LOAD, host.WALK_SYNC}),
-    (_fft, {host.WALK_REGION, host.WALK_LOAD, host.WALK_SYNC,
-            host.WALK_COPY}),
-    (_matmul, {host.WALK_LOAD, host.WALK_SYNC, host.WALK_REDUCE})],
-    ids=["conv8", "fft32", "matmul4_streamed"])
-def test_walk_items_are_spans_and_counters(build, kinds, rng, tmp_path):
-    progs = [build(rng) for _ in range(N)]
-    backend = get_backend("pallas")
-    wl = KviWorkload.homogeneous(progs, name="walk-spans")
-    res, spans = _profiled(tmp_path, lambda: backend.run_workload(wl))
-
-    want = _planned_items(res.workload.entries[0].program)
-    assert {k for k, n in want.items() if n} == kinds
+def _by_name(spans):
     by_name = {}
     for s in spans:
         by_name.setdefault(s[0], []).append(s)
-    for name, n in want.items():
-        assert len(by_name.get(name, ())) == n, name
-    assert len(by_name[host.WALK_STAGE]) == len(by_name[host.WALK_OUTPUTS]) \
-        == 1
+    return by_name
 
-    call, = by_name[host.RUN_WORKLOAD]
-    prepare, = by_name[host.PREPARE]
-    walk, = by_name[host.WALK]
-    assert _inside(prepare, call) and _inside(walk, call)
-    assert prepare[2] <= walk[1]
-    items = [s for s in spans if s[0].startswith(host.WALK + ".")]
-    assert all(_inside(s, walk) for s in items)
-    assert set(by_name) <= set(host.SPANS)
 
-    assert res.meta["host_syncs"] == want[host.WALK_SYNC]
-    assert res.meta["eager_ops"] == (want[host.WALK_LOAD]
-                                     + want[host.WALK_COPY]
-                                     + want[host.WALK_REDUCE])
-    assert res.meta["pallas_calls"] == (want[host.WALK_REGION]
-                                        + want[host.WALK_REDUCE])
+@pytest.mark.parametrize("build,kinds", [
+    (_conv, {"regions"}),
+    (_fft, {"regions"}),
+    (_matmul, {"reductions"})],
+    ids=["conv8", "fft32", "matmul4_streamed"])
+def test_walk_is_one_compiled_call(build, kinds, rng, tmp_path):
+    progs = [build(rng) for _ in range(N)]
+    backend = get_backend("pallas")
+    wl = KviWorkload.homogeneous(progs, name="walk-spans")
+    oracle = get_backend("oracle")
+    for run, built in (("cold", True), ("warm", False)):
+        res, spans = _profiled(tmp_path / run,
+                               lambda: backend.run_workload(wl))
+        by_name = _by_name(spans)
+        call, = by_name[host.RUN_WORKLOAD]
+        prepare, = by_name[host.PREPARE]
+        walk, = by_name[host.WALK]
+        assert _inside(prepare, call) and _inside(walk, call)
+        assert prepare[2] <= walk[1]
+        for name in (host.WALK_STAGE, host.WALK_CALL, host.WALK_SYNC,
+                     host.WALK_OUTPUTS):
+            span, = by_name[name]
+            assert _inside(span, walk), name
+        assert by_name[host.WALK_CALL][0][2] <= by_name[host.WALK_SYNC][0][1]
+        assert len(by_name.get(host.WALK_BUILD, ())) == built
+        assert set(by_name) <= set(host.SPANS)
 
+        regions, reductions = _planned_calls(res.workload.entries[0].program)
+        assert {k for k, n in (("regions", regions),
+                               ("reductions", reductions)) if n} == kinds
+        assert res.meta["walks"] == res.meta["host_syncs"] == 1
+        assert res.meta["walks_built"] == built
+        assert "eager_ops" not in res.meta
+        assert res.meta["pallas_calls"] == regions + reductions
+        cc = res.meta["compile_cache"]
+        assert (cc["misses"] > 0) if built else cc == {"hits": 1,
+                                                        "misses": 0}
+        for prog, got in zip(progs, res.entry_results):
+            for k, v in oracle.run(prog).outputs.items():
+                assert np.array_equal(v, got.outputs[k]), k
+
+
+def test_each_structure_is_a_walk_of_its_own(rng, tmp_path):
+    progs = [_conv(rng), _matmul(rng), _conv(rng)]
+    backend = get_backend("pallas")
+    wl = KviWorkload.composite({h: [p] for h, p in enumerate(progs)})
+    res, spans = _profiled(tmp_path, lambda: backend.run_workload(wl))
+    by_name = _by_name(spans)
+    walks = by_name[host.WALK]
+    assert res.meta["groups"] == res.meta["walks"] == len(walks) == 2
+    assert res.meta["walks_built"] == res.meta["host_syncs"] == 2
+    for name in (host.WALK_BUILD, host.WALK_CALL, host.WALK_SYNC):
+        assert len(by_name[name]) == 2
+        assert all(any(_inside(s, w) for w in walks)
+                   for s in by_name[name])
     oracle = get_backend("oracle")
     for prog, got in zip(progs, res.entry_results):
         for k, v in oracle.run(prog).outputs.items():
@@ -135,9 +157,11 @@ def test_counters_are_per_call_without_a_profiler(rng):
     backend = get_backend("pallas")
     first = backend.run_workload(KviWorkload.homogeneous(progs))
     again = backend.run_workload(KviWorkload.homogeneous(progs))
-    assert first.meta["host_syncs"] == again.meta["host_syncs"] > 0
-    assert first.meta["eager_ops"] == again.meta["eager_ops"] > 0
-    assert backend.host_syncs == 2 * first.meta["host_syncs"]
+    assert first.meta["host_syncs"] == again.meta["host_syncs"] == 1
+    assert first.meta["pallas_calls"] == again.meta["pallas_calls"] > 0
+    assert (first.meta["walks_built"], again.meta["walks_built"]) == (1, 0)
+    assert backend.host_syncs == 2
+    assert backend.walks_built == 1
 
 
 def test_engine_spans_frame_the_backend(tmp_path):

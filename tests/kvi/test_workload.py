@@ -256,6 +256,67 @@ class TestBatchedPallas:
             assert np.array_equal(r1.outputs["y"], want)
 
 
+    def test_warm_walk_builds_nothing(self):
+        """A second batch of the same structure and size reuses the
+        compiled walk: no build, no kernel-cache miss, one hit."""
+        from repro.kvi.pallas_backend import PallasBackend
+        progs, wants = zip(*[_saxpy(s) for s in range(4)])
+        pb = PallasBackend()
+        cold = pb.run_workload(KviWorkload.homogeneous(progs))
+        warm = pb.run_workload(KviWorkload.homogeneous(progs[::-1]))
+        assert cold.meta["walks_built"] == 1
+        assert cold.meta["compile_cache"]["misses"] > 0
+        assert warm.meta["walks"] == 1
+        assert warm.meta["walks_built"] == 0
+        assert warm.meta["compile_cache"] == {"hits": 1, "misses": 0}
+        for r, want in zip(warm.entry_results, wants[::-1]):
+            assert np.array_equal(r.outputs["y"], want)
+
+    @pytest.mark.parametrize("change", ["N", "structure"])
+    def test_new_batch_size_or_structure_builds_one_walk(self, change):
+        from repro.kvi.pallas_backend import PallasBackend
+        pb = PallasBackend()
+        pb.run_workload(KviWorkload.homogeneous(
+            [_saxpy(s)[0] for s in range(4)]))
+        if change == "N":
+            progs, wants = zip(*[_saxpy(s) for s in range(3)])
+        else:
+            progs, wants = zip(*[_saxpy(s, scalar=5) for s in range(4)])
+        res = pb.run_workload(KviWorkload.homogeneous(progs))
+        assert res.meta["walks"] == res.meta["walks_built"] == 1
+        assert pb.walks_built == 2
+        for r, want in zip(res.entry_results, wants):
+            assert np.array_equal(r.outputs["y"], want)
+
+    @pytest.mark.parametrize("n_batch", [1, 3])
+    def test_stored_buffer_is_read_back(self, n_batch):
+        """A ``kmemld`` of a buffer an earlier ``kmemstr`` wrote reads
+        what was stored, inside the one compiled walk."""
+        from repro.kvi.pallas_backend import PallasBackend
+        progs = []
+        for s in range(n_batch):
+            x = np.random.default_rng(s).integers(-100, 100, 16)
+            b = KviProgramBuilder("round_trip")
+            hx = b.mem_in("x", x.astype(np.int32))
+            v, w = b.vreg("v", 16), b.vreg("w", 16)
+            b.kmemld(v, hx)
+            b.ksvmulsc(v, v, scalar=3)
+            hy = b.mem_out("y", 16)
+            b.kmemstr(hy, v)
+            b.kmemld(w, hy)
+            b.kaddv(w, w, v)
+            hz = b.mem_out("z", 16)
+            b.kmemstr(hz, w)
+            progs.append(b.build())
+        wl = KviWorkload.homogeneous(progs)
+        res = PallasBackend(passes=()).run_workload(wl)
+        ro = get_backend("oracle", passes=()).run_workload(wl)
+        assert res.meta["host_syncs"] == 1
+        for p, a, b in zip(progs, ro.entry_results, res.entry_results):
+            _outputs_equal(a, b)
+            assert np.array_equal(b.outputs["z"], 6 * p.mem_init[0])
+
+
 class TestScheduler:
     def test_earliest_finish_packing(self):
         from repro.kvi.scheduler import HartScheduler

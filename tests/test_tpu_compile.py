@@ -2,10 +2,10 @@
 
 Interpret-mode tests cannot see what Mosaic refuses: unaligned tiles,
 sub-word arithmetic the vector unit lacks, scalar stores to VMEM. These
-tests compile the KVI fused-region kernel, the reduction kernels and the
-``llama3.2-1b`` decode step for one v5e chip, so a kernel change that the
-chip's compiler would refuse fails here. Nothing runs; a compile that
-passes is not a chip run.
+tests compile the KVI fused-region kernel, the reduction kernels, whole
+batched walks and the ``llama3.2-1b`` decode step for one v5e chip, so
+a kernel change that the chip's compiler would refuse fails here.
+Nothing runs; a compile that passes is not a chip run.
 """
 import os
 
@@ -78,6 +78,28 @@ def test_reduction_compiles(one_chip, mosaic, kernel, n):
         # the backend's form: one launch reduces every row of a batch
         x = jax.ShapeDtypeStruct((8, n), jnp.int16, sharding=one_chip)
         _compile(kdotp_mod.reduce_rows, x)
+
+
+@pytest.mark.parametrize("kernel", ["conv32", "fft32"])
+def test_served_walk_compiles(one_chip, mosaic, kernel):
+    """The whole batched walk, every region and register-file update,
+    compiles as one program for the chip."""
+    import numpy as np
+    from repro.kvi.passes import PassPipeline
+    from repro.kvi.programs import conv2d_program, fft_program
+
+    if kernel == "conv32":
+        prog = conv2d_program(np.zeros((32, 32), np.int32),
+                              np.ones((3, 3), np.int32), shift=3)
+    else:
+        z = np.zeros(32, np.int32)
+        prog = fft_program(z, z)
+    prog = PassPipeline.from_spec(None).run(prog)
+    walk, args, spec = pb.PallasBackend(passes=())._walk_fn(prog, 8)
+    compiled = _compile(walk, *[jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                     sharding=one_chip)
+                                for a in args])
+    assert compiled.as_text().count("tpu_custom_call") >= spec.fused_calls > 0
 
 
 def test_llama_decode_step_fits_one_chip(one_chip):
