@@ -5,8 +5,9 @@ One command runs one cell (one entry of ``BENCHMARK.json``'s
 
     python -m bench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Everything that belongs to one configuration, traffic mix, metric or
-runner kind is a file of its own, found by name:
+Everything that belongs to one configuration, traffic mix, metric,
+runner kind or kernel is a file of its own, found by name:
 ``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json``,
-``bench/metrics/<metric>.py`` and ``bench/runners/<kind>.py``.
+``bench/metrics/<metric>.py``, ``bench/runners/<kind>.py`` and
+``bench/kernels/<kernel>.py``.
 """

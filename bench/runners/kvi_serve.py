@@ -10,7 +10,8 @@ backend, whose ``KernelCache`` holds the warm kernels, and one
 :class:`RecordingBackend`, which times each batch and keeps every
 request's inputs and outputs, because ``ServeEngine.run`` returns none.
 After the window every request of the window is compared with the plain
-reference in ``bench/reference.py``.
+reference of the configuration's kernel (``bench/kernels/<kernel>.py``),
+which also gives the request template's program and its work.
 """
 from __future__ import annotations
 
@@ -20,10 +21,9 @@ from typing import Dict, List, Mapping, Optional
 
 import numpy as np
 
-from bench import reference, tracing
+from bench import spec, tracing
 from bench.records import Batch, Request, RunRecord, Step
 from bench.traffic import is_open, open_arrivals, sub_seed
-from bench.work import request_work
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 #: an open loop stops sending this long after the window's close; what
@@ -99,23 +99,13 @@ def build_template(config: Mapping, trace_cache, control: bool = False):
     profiled as the serving path does it. ``control`` builds it at the
     program's own lower precision (``control_elem_bytes``)."""
     from repro.kvi.passes import PassPipeline
-    from repro.kvi.programs import conv2d_program, fft_program
     from repro.kvi.scheduler import simulated_profile
     from repro.kvi.serving import KernelTemplate, template_key
 
     kernel = config["kernel"]
     eb = config["control_elem_bytes"] if control else config["elem_bytes"]
-    if kernel == "conv":
-        S = config["image_size"]
-        prog = conv2d_program(np.zeros((S, S), np.int32),
-                              reference.conv_filter(config),
-                              shift=config["shift"], elem_bytes=eb)
-    elif kernel == "fft":
-        z = np.zeros(config["points"], np.int32)
-        prog = fft_program(z, z, elem_bytes=eb)
-    else:
-        raise ValueError(f"kvi_serve serves conv or fft, not {kernel!r}")
-    prog = PassPipeline.from_spec(None).run(prog)
+    prog = PassPipeline.from_spec(None).run(
+        spec.kernel(kernel).program(config, eb))
     profile = simulated_profile(prog, None, trace_cache=trace_cache)
     return KernelTemplate(template_key(kernel, eb), kernel, eb, prog,
                           frozenset(config["data_mems"]), profile,
@@ -133,7 +123,7 @@ def check(config: Mapping, requests: List[Request],
         names = sorted(got[0][0])
         inputs = {n: np.stack([np.asarray(g[0][n]) for g in got])
                   for n in names}
-        want = reference.expected(config, inputs)
+        want = spec.kernel(config["kernel"]).expected(config, inputs)
         for i, g in enumerate(got):
             diff = 0
             for name, w in want.items():
@@ -168,7 +158,7 @@ def run(config: Mapping, traffic: Mapping, *, seed: int, seconds: float,
     tpl = build_template(config, tc, control=control)
     inner = get_backend("pallas", passes=())
     rec = RecordingBackend(inner, config["data_mems"])
-    ops, nbytes = request_work(config)
+    ops, nbytes = spec.kernel(config["kernel"]).work(config)
     phases.mark("template")
 
     # prewarm the bucket sizes this traffic produces, and no others

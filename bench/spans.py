@@ -4,11 +4,12 @@ idle time named by them.
 The served path marks its layer boundaries with ``kvi.*`` host spans
 (``repro/kvi/obs/host.py`` in the program): ``kvi.engine.run`` and its
 steps, ``kvi.backend.run_workload``, one ``kvi.walk`` per batched walk
-and one ``kvi.walk.<item>`` per walk item. They share the device
-trace's clock, so each idle gap of the device can be named by the
-innermost span, the harness's (``bench/tracing.py``) or the program's,
-over its middle. :func:`summarize` reduces a trace (the plain structure
-of :func:`bench.tracing.load`) to
+and its parts (``kvi.walk.build``, ``.stage``, ``.call``, ``.sync``,
+``.outputs``). They share the device trace's clock, so each idle gap of
+the device can be named by the innermost span, the harness's
+(``bench/tracing.py``) or the program's, over its middle.
+:func:`summarize` reduces a trace (the plain structure of
+:func:`bench.tracing.load`) to
 
 - ``program_spans``: per span name, ``[count, seconds]`` clipped to the
   ``bench.window`` span;
@@ -35,9 +36,8 @@ ENGINE_RUN = "kvi.engine.run"
 RUN_WORKLOAD = "kvi.backend.run_workload"
 WALK = "kvi.walk"
 WALK_SYNC = "kvi.walk.sync"
-#: walk items that issue device work, one dispatch each
-WALK_DISPATCH = ("kvi.walk.region", "kvi.walk.load", "kvi.walk.copy",
-                 "kvi.walk.reduce")
+#: the walk's one dispatch of its compiled device program
+WALK_CALL = "kvi.walk.call"
 
 Span = Tuple[str, float, float]
 
@@ -119,10 +119,10 @@ def summarize(trace: Dict, top: int = 10) -> Dict[str, object]:
 
 def readings(totals: Dict[str, Sequence[float]]) -> Dict[str, float]:
     """From ``program_spans``: per walk, its milliseconds in host syncs
-    (``walk_sync_ms_per_batch``), in dispatching device work
+    (``walk_sync_ms_per_batch``), in dispatching its device program
     (``walk_dispatch_ms_per_batch``) and in neither
-    (``walk_self_ms_per_batch``: the interpreter loop, staging, output
-    copies); per engine run, its milliseconds outside the backend
+    (``walk_self_ms_per_batch``: staging, output copies, a build); per
+    engine run, its milliseconds outside the backend
     (``engine_self_ms_per_step``). A reading whose parent span is not
     in the trace is left out."""
     def s(name: str) -> float:
@@ -131,7 +131,7 @@ def readings(totals: Dict[str, Sequence[float]]) -> Dict[str, float]:
     walks = totals.get(WALK, (0, 0.0))[0]
     if walks:
         sync = s(WALK_SYNC)
-        dispatch = sum(s(n) for n in WALK_DISPATCH)
+        dispatch = s(WALK_CALL)
         out["walk_sync_ms_per_batch"] = 1e3 * sync / walks
         out["walk_dispatch_ms_per_batch"] = 1e3 * dispatch / walks
         out["walk_self_ms_per_batch"] = \

@@ -1,14 +1,19 @@
 """Finds what ``BENCHMARK.json`` names: each configuration, traffic mix,
-metric reader and runner kind is a file of its own under ``bench/``, so
-a later change adds a cell, a mix, a metric or a runner by adding files.
+metric reader, runner kind and kernel is a file of its own under
+``bench/``, so a later change adds a cell, a mix, a metric, a runner or
+a kernel by adding files.
 """
 from __future__ import annotations
 
 import importlib
 import json
+import pkgutil
 import re
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Mapping, Optional
+
+import bench.kernels
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
@@ -55,6 +60,21 @@ def load_traffic(name: str, base: Path = BENCH_DIR) -> Dict:
 def runner(kind: str) -> Callable:
     """``bench/runners/<kind>.py``'s ``run``."""
     return importlib.import_module(f"bench.runners.{_checked(kind)}").run
+
+
+def kernel(name: str) -> ModuleType:
+    """``bench/kernels/<name>.py``: its ``program``, ``expected`` and
+    ``work`` (see ``bench/kernels/__init__.py``)."""
+    module = f"bench.kernels.{_checked(name)}"
+    try:
+        return importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        known = sorted(m.name for m in
+                       pkgutil.iter_modules(bench.kernels.__path__))
+        raise KeyError(f"no kernel file {name}.py in bench/kernels; "
+                       f"known: {known}") from None
 
 
 def reader(metric: str) -> Callable:

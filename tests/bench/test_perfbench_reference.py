@@ -4,7 +4,8 @@ import numpy as np
 import pytest
 
 from _perfbench_helpers import DATA
-from bench import reference, spec
+from bench import spec
+from bench.kernels import conv, fft
 
 
 def _oracle_and_reference(config, n, seed):
@@ -18,7 +19,7 @@ def _oracle_and_reference(config, n, seed):
     inputs = {name: np.stack([next(p.mem_init[m.id] for m in p.mems
                                    if m.name == name) for p in progs])
               for name in config["data_mems"]}
-    return got, reference.expected(config, inputs)
+    return got, spec.kernel(config["kernel"]).expected(config, inputs)
 
 
 @pytest.mark.parametrize("name,base,n", [
@@ -38,14 +39,14 @@ def test_fft_reference_of_an_impulse_is_flat():
     # impulse's path beyond a product with w^0 = 1 in Q15)
     re = np.zeros((1, 32), np.int32)
     re[0, 0] = 64
-    out_re, out_im = reference.fft_dif(re, np.zeros_like(re), 15)
+    out_re, out_im = fft.fft_dif(re, np.zeros_like(re), 15)
     assert (out_re == 64).all() and (out_im == 0).all()
 
 
 def test_conv_reference_by_hand():
     img = np.arange(16, dtype=np.int32).reshape(1, 4, 4)
     filt = np.array([[0, 0, 0], [0, 2, 0], [0, 0, 1]], np.int32)
-    out = reference.conv2d(img, filt, shift=1)
+    out = conv.conv2d(img, filt, shift=1)
     # centre tap 2*x[i+1, j+1], corner tap x[i+2, j+2], then >> 1
     want = (2 * img[0, 1:3, 1:3] + img[0, 2:4, 2:4]) >> 1
     np.testing.assert_array_equal(out[0], want)
@@ -55,4 +56,4 @@ def test_references_wrap_at_32_bits():
     big = np.full((1, 3, 3), 2**30, np.int32)
     filt = np.full((3, 3), 4, np.int32)
     # 9 * 2^32 wraps to 0 in 32 bits
-    assert reference.conv2d(big, filt, 0)[0, 0, 0] == 0
+    assert conv.conv2d(big, filt, 0)[0, 0, 0] == 0

@@ -186,6 +186,34 @@ def test_configs_mixes_metrics_and_runners_are_found_by_file(tmp_path,
         bench_spec, "tiny.conv.closed", run, CPU, True)["metrics"]
 
 
+def test_kernels_are_found_by_file(tmp_path, monkeypatch):
+    """A kernel that is only a file outside ``bench/kernels``, named only
+    by a configuration outside ``bench/configs``, is served and checked."""
+    import bench.kernels
+    from _perfbench_helpers import DATA
+    (tmp_path / "kernels").mkdir()
+    (tmp_path / "kernels" / "conv_twin.py").write_text(
+        "from bench.kernels.conv import expected, program, work  # noqa\n")
+    config = dict(spec.load_config("tiny-conv", DATA), name="tiny-twin",
+                  kernel="conv_twin")
+    for kind, name, data in (("configs", "tiny-twin", config),
+                             ("traffic", "closed4",
+                              spec.load_traffic("closed4", DATA))):
+        (tmp_path / kind).mkdir()
+        (tmp_path / kind / f"{name}.json").write_text(json.dumps(data))
+    monkeypatch.setattr(bench.kernels, "__path__",
+                        list(bench.kernels.__path__)
+                        + [str(tmp_path / "kernels")])
+    bench_spec = small_bench()
+    bench_spec["workloads"].append({"name": "tiny.twin.closed",
+                                    "config": "tiny-twin",
+                                    "traffic": "closed4", "chips": 1})
+    _, line = bench_run.execute(bench_spec, "tiny.twin.closed", 2**31 + 29,
+                                0.5, False, CPU, 0.0, base=tmp_path)
+    assert line["correct"] is True, line["checks"]
+    assert line["attempted"] > 0 and line["failed"] == 0
+
+
 def test_names_outside_the_alphabet_are_refused():
     for bad in ("../x", "a b", "a/b", ""):
         with pytest.raises(ValueError):
@@ -198,6 +226,9 @@ def test_every_cell_of_the_benchmark_resolves():
         config = spec.load_config(w["config"])
         spec.load_traffic(w["traffic"])
         assert callable(spec.runner(config["runner"]))
+        kernel = spec.kernel(config["kernel"])
+        assert all(callable(getattr(kernel, f))
+                   for f in ("program", "expected", "work"))
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert callable(spec.reader(m["name"]))
 

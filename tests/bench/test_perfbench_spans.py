@@ -20,17 +20,17 @@ def _trace(device_events, host_events):
             {"name": "XLA Ops", "events": device_events}]}]}
 
 
-# one engine run over one walk: a region, a copy and a sync, then the
+# one engine run over one walk: staging, the call and a sync, then the
 # outputs; the window opens at 0 and closes at 100 ms
 HOST = [["bench.window", 0, 100 * ms],
-        ["kvi.walk.copy", -5 * ms, 2 * ms],           # before the window
+        ["kvi.walk.call", -5 * ms, 2 * ms],           # before the window
         ["engine.run", 5 * ms, 80 * ms],
         ["kvi.engine.run", 6 * ms, 74 * ms],
         ["backend.run_workload", 10 * ms, 70 * ms],
         ["kvi.backend.run_workload", 11 * ms, 68 * ms],
         ["kvi.walk", 12 * ms, 66 * ms],
-        ["kvi.walk.region", 13 * ms, 10 * ms],
-        ["kvi.walk.copy", 30 * ms, 20 * ms],
+        ["kvi.walk.stage", 13 * ms, 10 * ms],
+        ["kvi.walk.call", 30 * ms, 20 * ms],
         ["kvi.walk.sync", 55 * ms, 20 * ms],
         ["kvi.walk.outputs", 76 * ms, 1 * ms],
         ["kvi.walk.sync", 98 * ms, 4 * ms]]           # cut by the close
@@ -41,7 +41,7 @@ DEVICE = [["fusion", 16 * ms, 2 * ms], ["copy", 45 * ms, 1 * ms],
 def test_span_totals_are_clipped_to_the_window():
     s = spans.summarize(_trace(DEVICE, HOST))
     t = s["program_spans"]
-    assert t["kvi.walk.copy"] == [1, pytest.approx(0.020)]
+    assert t["kvi.walk.call"] == [1, pytest.approx(0.020)]
     assert t["kvi.walk.sync"] == [2, pytest.approx(0.022)]
     assert t["kvi.walk"] == [1, pytest.approx(0.066)]
     assert "engine.run" not in t and "bench.window" not in t
@@ -49,17 +49,17 @@ def test_span_totals_are_clipped_to_the_window():
 
 def test_gaps_are_named_by_the_innermost_span_harness_or_program():
     s = spans.summarize(_trace(DEVICE, HOST))
-    # 18..45 ms: middle 31.5 in the copy, inside backend.run_workload
+    # 18..45 ms: middle 31.5 in the call, inside backend.run_workload
     # and kvi.walk; 61..100: middle 80.5 in engine.run only (the
     # program's engine span ended at 80, its walk at 78); 0..16: middle
     # 8 in kvi.engine.run; 46..60: middle 53 in kvi.walk between items
     assert s["idle_gaps"] == [["engine.run", pytest.approx(0.039)],
-                              ["kvi.walk.copy", pytest.approx(0.027)],
+                              ["kvi.walk.call", pytest.approx(0.027)],
                               ["kvi.engine.run", pytest.approx(0.016)],
                               ["kvi.walk", pytest.approx(0.014)]]
     assert s["idle_by_span"] == {
         "engine.run": pytest.approx(0.039),
-        "kvi.walk.copy": pytest.approx(0.027),
+        "kvi.walk.call": pytest.approx(0.027),
         "kvi.engine.run": pytest.approx(0.016),
         "kvi.walk": pytest.approx(0.014)}
 
@@ -76,10 +76,11 @@ def test_readings_split_the_walk_and_the_engine():
     t = spans.summarize(_trace(DEVICE, HOST))["program_spans"]
     r = spans.readings(t)
     # per walk: sync 22 ms (one clipped outside the walk counts too),
-    # dispatch 10 + 20 ms, self 66 - 22 - 30 ms; engine 74 - 68 ms
+    # dispatch (the call) 20 ms, self 66 - 22 - 20 ms (staging in it);
+    # engine 74 - 68 ms
     assert r == {"walk_sync_ms_per_batch": pytest.approx(22.0),
-                 "walk_dispatch_ms_per_batch": pytest.approx(30.0),
-                 "walk_self_ms_per_batch": pytest.approx(14.0),
+                 "walk_dispatch_ms_per_batch": pytest.approx(20.0),
+                 "walk_self_ms_per_batch": pytest.approx(24.0),
                  "engine_self_ms_per_step": pytest.approx(6.0)}
 
 

@@ -4,7 +4,9 @@ import pytest
 import _perfbench_helpers  # noqa: F401
 from bench import spec
 from bench.peaks import peak_for
-from bench.work import conv_work, fft_work, least_time_s, request_work
+from bench.kernels.conv import conv_work
+from bench.kernels.fft import fft_work
+from bench.work import least_time_s
 
 
 def test_conv32_hand_count():
@@ -27,8 +29,10 @@ def test_fft256_hand_count():
 
 
 def test_request_work_reads_the_configuration_files():
-    assert request_work(spec.load_config("kvi-conv32")) == conv_work(32, 3, 4)
-    assert request_work(spec.load_config("kvi-fft256")) == fft_work(256, 4)
+    for name, want in (("kvi-conv32", (18432, 8756)),
+                       ("kvi-fft256", (10240, 5120))):
+        config = spec.load_config(name)
+        assert spec.kernel(config["kernel"]).work(config) == want
 
 
 def test_least_time_takes_the_larger_bound():
