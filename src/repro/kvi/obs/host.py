@@ -43,6 +43,12 @@ SPANS = (RUN_WORKLOAD, PREPARE, WALK, WALK_BUILD, WALK_STAGE, WALK_CALL,
          WALK_SYNC, WALK_OUTPUTS, ENGINE_RUN, ENGINE_ADMIT,
          ENGINE_INSTANTIATE, ENGINE_REPORT)
 
+# Device-side names: ``jax.named_scope``s inside the traced walk. They
+# are no host spans; they name the compiled walk's ops (the HLO name of
+# a region's or reduction's custom call starts with its scope).
+REGION_SCOPE = "kvi.region"                 # one fused element-wise region
+REDUCE_SCOPE = "kvi.reduce"                 # one batched reduction kernel
+
 _NO_SPAN = contextlib.nullcontext()
 
 

@@ -204,7 +204,19 @@ def _make_fused_caller(program: Tuple[SlotOp, ...], in_slots: tuple,
             interpret=interpret_mode(),
         )(*arrs)
 
-    return call
+    return _named(call, host.REGION_SCOPE)
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under the function name ``name``. A jitted kernel traced
+    into the compiled walk is inlined under its function's name, so this
+    is the name its custom call carries in the HLO and the device trace
+    (``kvi.reduce.12``), as the named scope around it names its other
+    ops."""
+    def named(*args):
+        return fn(*args)
+    named.__name__ = named.__qualname__ = name
+    return named
 
 
 def fused_elementwise_call(program: Sequence[SlotOp],
@@ -406,7 +418,8 @@ class PallasBackend(BackendBase):
         key = ("red", i.op.value, i.scalar, a.shape[0], i.length,
                str(a.dtype))
         fn = self.kernel_cache.get(
-            key, lambda: jax.jit(self._make_reducer(i.op, i.scalar)))
+            key, lambda: jax.jit(_named(self._make_reducer(i.op, i.scalar),
+                                        host.REDUCE_SCOPE)))
         if i.op in (KviOp.KDOTP, KviOp.KDOTPPS):
             b = self._slice(regfile, (i.src2.id, i.src2.offset, i.length))
             r = fn(a, b)
@@ -459,7 +472,8 @@ class PallasBackend(BackendBase):
             for idx, i in instrs:
                 region = region_at.get(idx)
                 if region is not None:
-                    self._run_region(region, regfile)
+                    with jax.named_scope(host.REGION_SCOPE):
+                        self._run_region(region, regfile)
                     spec.fused_calls += 1
                 elif idx in fused:
                     continue             # executed with its region head
@@ -479,7 +493,8 @@ class PallasBackend(BackendBase):
                     self._set(regfile, (i.dst.id, i.dst.offset, i.length),
                               v)
                 else:
-                    self._reduce(i, regfile)
+                    with jax.named_scope(host.REDUCE_SCOPE):
+                        self._reduce(i, regfile)
                     spec.reduce_calls += 1
             # one array per dtype, so the host fetches the walk at once
             by_dtype: Dict[str, List[int]] = {}
@@ -558,7 +573,8 @@ class PallasBackend(BackendBase):
 
         ``meta`` reports the run's observability: structural ``groups``,
         the ``walks`` run and the ``walks_built`` (traced and compiled)
-        in this call, the ``pallas_calls`` those walks issued,
+        in this call, the ``pallas_calls`` those walks issued and the
+        ``reductions`` among them (batched reduction kernels),
         ``host_syncs`` (device->host fetches, one per walk), this call's
         kernel-cache hit/miss deltas (``compile_cache``: a warm walk is
         one hit) and ``wall_s`` — the real execution walltime (outputs
@@ -567,7 +583,9 @@ class PallasBackend(BackendBase):
         walltime axis and the serving engine read these directly.
         Inside a profiler session the call, its preparation and each
         walk's build, staging, call, fetch and outputs are ``kvi.*``
-        host spans (:mod:`repro.kvi.obs.host`)."""
+        host spans (:mod:`repro.kvi.obs.host`); inside the compiled walk
+        each region and reduction is under a ``kvi.region`` or
+        ``kvi.reduce`` named scope, which names its device ops."""
         t0 = time.perf_counter()
         with host_span(host.RUN_WORKLOAD,
                        entries=len(workload.entries)) as span:
@@ -579,6 +597,7 @@ class PallasBackend(BackendBase):
                                       []).append(idx)
             span.set_metadata(groups=len(groups))
             calls0 = self.fused_calls + self.reduce_calls
+            reductions0 = self.reduce_calls
             syncs0, built0 = self.host_syncs, self.walks_built
             cc0 = (self.kernel_cache.hits, self.kernel_cache.misses)
             entry_outputs: List[Optional[Dict[str, np.ndarray]]] = \
@@ -594,6 +613,7 @@ class PallasBackend(BackendBase):
             results = tuple(BackendResult(self.name, out)
                             for out in entry_outputs)
         calls = self.fused_calls + self.reduce_calls - calls0
+        reductions = self.reduce_calls - reductions0
         built = self.walks_built - built0
         cc = {"hits": self.kernel_cache.hits - cc0[0],
               "misses": self.kernel_cache.misses - cc0[1]}
@@ -602,6 +622,7 @@ class PallasBackend(BackendBase):
             m = self.obs.metrics
             m.counter("pallas.runs").inc()
             m.counter("pallas.calls").inc(calls)
+            m.counter("pallas.reductions").inc(reductions)
             m.counter("pallas.walks").inc(len(groups))
             m.counter("pallas.walks_built").inc(built)
             m.absorb("pallas.compile_cache", cc)
@@ -611,6 +632,7 @@ class PallasBackend(BackendBase):
                   "walks": len(groups),
                   "walks_built": built,
                   "pallas_calls": calls,
+                  "reductions": reductions,
                   "host_syncs": self.host_syncs - syncs0,
                   "compile_cache": cc,
                   "wall_s": wall_s})

@@ -126,8 +126,11 @@ def matmul_program(A: np.ndarray, B: np.ndarray, shift: int = 0,
         return b.build(alg_ops=2 * n * m * p, kind="matmul", n=n, p=p,
                        shift=shift, resident=True, elem_bytes=elem_bytes)
 
-    # streamed path: per output element, kdotp(A_row, B_col)
+    # streamed path: per output element, kdotp(A_row, B_col); B is one
+    # main-memory buffer per column, each streamed in again for every row
     Bt = np.ascontiguousarray(B.astype(dt).T)
+    hcols = [b.mem_in(f"bcol{j}", Bt[j], elem_bytes=elem_bytes)
+             for j in range(p)]
     arow = b.vreg("arow", m, elem_bytes=elem_bytes)
     bcol = b.vreg("bcol", m, elem_bytes=elem_bytes)
     acc = b.vreg("acc", p, elem_bytes=elem_bytes)
@@ -138,8 +141,7 @@ def matmul_program(A: np.ndarray, B: np.ndarray, shift: int = 0,
         b.kmemld(arow, hA)
         for j in range(p):
             b.scalar(3)                           # col pointer, loop, store rd
-            hcol = b.mem_in(f"bcol{i}_{j}", Bt[j], elem_bytes=elem_bytes)
-            b.kmemld(bcol, hcol)
+            b.kmemld(bcol, hcols[j])
             if shift:
                 b.kdotpps(acc[j], arow, bcol, shift)
             else:

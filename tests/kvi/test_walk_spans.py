@@ -125,6 +125,7 @@ def test_walk_is_one_compiled_call(build, kinds, rng, tmp_path):
         assert res.meta["walks_built"] == built
         assert "eager_ops" not in res.meta
         assert res.meta["pallas_calls"] == regions + reductions
+        assert res.meta["reductions"] == reductions
         cc = res.meta["compile_cache"]
         assert (cc["misses"] > 0) if built else cc == {"hits": 1,
                                                         "misses": 0}

@@ -102,6 +102,42 @@ def test_served_walk_compiles(one_chip, mosaic, kernel):
     assert compiled.as_text().count("tpu_custom_call") >= spec.fused_calls > 0
 
 
+@pytest.mark.parametrize("kernel", ["conv32", "fft32", "matmul8_streamed"])
+def test_served_walk_names_its_kernels(one_chip, mosaic, kernel):
+    """Inside the compiled walk every region's custom call is named
+    ``kvi.region.<k>`` and every reduction's ``kvi.reduce.<k>``, the
+    names the device trace gives their ops."""
+    import re
+
+    import numpy as np
+    from repro.kvi.obs import host
+    from repro.kvi.passes import PassPipeline
+    from repro.kvi.programs import (conv2d_program, fft_program,
+                                    matmul_program)
+
+    if kernel == "conv32":
+        prog = conv2d_program(np.zeros((32, 32), np.int32),
+                              np.ones((3, 3), np.int32), shift=3)
+    elif kernel == "fft32":
+        z = np.zeros(32, np.int32)
+        prog = fft_program(z, z)
+    else:
+        prog = matmul_program(np.ones((8, 8), np.int32),
+                              np.zeros((8, 8), np.int32), shift=2,
+                              resident=False)
+    prog = PassPipeline.from_spec(None).run(prog)
+    walk, args, spec = pb.PallasBackend(passes=())._walk_fn(prog, 8)
+    text = _compile(walk, *[jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                 sharding=one_chip)
+                            for a in args]).as_text()
+    names = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    scopes = [n.rsplit(".", 1)[0] for n in names]
+    assert scopes.count(host.REGION_SCOPE) == spec.fused_calls
+    assert scopes.count(host.REDUCE_SCOPE) == spec.reduce_calls
+    assert len(names) == spec.fused_calls + spec.reduce_calls > 0
+
+
 def test_llama_decode_step_fits_one_chip(one_chip):
     from repro.configs import get_spec
     from repro.models import model_zoo as zoo
